@@ -11,9 +11,9 @@ Three measurements, all on whatever backend is present:
 * interpret-mode Pallas parity against the XLA oracle on a small tensor
   (structural correctness — interpret wall time itself is meaningless),
   plus the compiled kernel's structural stats (VMEM tile bytes, grid,
-  threshold-search passes): the TPU-relevant numbers.  Re-pin on real
-  hardware by flipping ``repro.kernels.ops.INTERPRET`` to False and
-  re-running this bench there (README "Kernels").
+  threshold-search passes).  None of these is a TPU timing: on a TPU the
+  kernels run compiled, chosen by the platform, and are not timed here
+  (README "Kernels").
 
 The returned result dict carries ``speedup`` (unfused / fused seconds) as
 the tracked metric for the BENCH artifact.
@@ -101,13 +101,16 @@ def run(csv_writer):
                f"encode={'ok' if parity else 'MISMATCH'},"
                f"roundtrip={'ok' if rt_ok else 'MISMATCH'}")
 
-    # structural stats of the compiled Pallas encode kernel (TPU numbers)
-    kp = tk._lane_pad(kpb)
-    vmem_bytes = block * 4 + kp * 4 + (block // 32) * 4
+    # structural stats of the compiled Pallas wire kernels (f32): per grid
+    # step the encode holds a word-major (32, block/32) input tile, the
+    # (32, ceil(k/32)) value slots and the (1, block/32) bitmap words; the
+    # decode adds a (32, block/32) scratch tile it expands the values in
+    slots = tk._WORD * tk._slot_columns(kpb)
+    vmem_bytes = 2 * block * 4 + slots * 4 + (block // tk._WORD) * 4
     csv_writer("kernel_pallas_structure", 0.0,
                f"block={block},vmem_bytes={vmem_bytes},"
                f"search_iters={tk._SEARCH_BITS},grid={nb},"
-               f"values_lanes={kp}")
+               f"value_slots={slots}")
     return {"kernel": {
         "t_unfused_us": t_unfused * 1e6,
         "t_fused_us": t_fused * 1e6,

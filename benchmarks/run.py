@@ -75,6 +75,8 @@ def main() -> None:
     ap.add_argument("--json", action="store_true",
                     help="write a BENCH_<name>.json artifact per bench")
     args = ap.parse_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
 
     from . import (ablation_microbatch, churn, convergence, gpu_table,
                    joint_planning, kernel_bench, latency, ratio_sweep,

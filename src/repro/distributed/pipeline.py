@@ -26,19 +26,19 @@ workload).  n_layers must divide evenly into stages.
 from __future__ import annotations
 
 import functools
-from typing import Any, Callable, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelCfg
-from repro.core.compression import boundary_compress, ratio_to_k
+from repro.core.compression import (KernelPolicy, boundary_compress,
+                                    ratio_to_k)
 from repro.models import causal_lm
 from repro.models.causal_lm import _dense_block
-from repro.models.layers import cross_entropy, dense, embed, norm_apply
+from repro.models.layers import cross_entropy, embed, norm_apply
 
 
 def stage_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -56,14 +56,18 @@ def pod_edge_ratios(mesh: Mesh, base_ratio: float,
     R_i for an intra-pod ICI edge vs a pod-crossing edge differs by ~the
     bandwidth gap; with only two tiers, Eq. 7 degenerates to: slowest edges
     get ``3r``, fast edges get 1 (max(1, 3r·R_i/R_max) with R_i ≪ R_max).
+    A slow ratio at or below the encoding's break-even (``index_overhead``,
+    i.e. ``base_ratio <= 1``) would inflate the wire, so it sends dense —
+    the same clamp as :func:`repro.core.compression.adaptive_ratios`.
     """
     ns = n_stages(mesh)
     ratios = np.ones(ns)            # edge i: stage i -> i+1 (cyclic unused)
-    if "pod" in mesh.axis_names:
+    slow = index_overhead * base_ratio
+    if "pod" in mesh.axis_names and slow > index_overhead:
         per_pod = mesh.shape["model"]
         for s in range(ns - 1):
             if (s + 1) % per_pod == 0:           # crossing into next pod
-                ratios[s] = max(1.0, index_overhead * base_ratio)
+                ratios[s] = slow
     return ratios
 
 
@@ -79,11 +83,29 @@ def _split_stage_params(cfg: ModelCfg, params: Dict[str, Any], ns: int):
     return blocks, rest
 
 
+def place_params(cfg: ModelCfg, mesh: Mesh, rng: jax.Array
+                 ) -> Dict[str, Any]:
+    """``causal_lm.init`` materialized directly in the stage placement, so
+    no device ever holds the whole model: the stacked blocks split along
+    their layer axis over the stage axes (each chip holds exactly its
+    stage's layers, in the order :func:`_split_stage_params` cuts them);
+    embeddings, final norm and head replicated."""
+    def sharding(key):
+        return NamedSharding(mesh, P(stage_axes(mesh)) if key == "blocks"
+                             else P())
+
+    init = functools.partial(causal_lm.init, cfg)
+    shardings = {k: jax.tree_util.tree_map(lambda _: sharding(k), v)
+                 for k, v in jax.eval_shape(init, rng).items()}
+    return jax.jit(init, out_shardings=shardings)(rng)
+
+
 def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
                            base_ratio: float = 1.0,
-                           use_kernel: bool = False) -> Callable:
+                           use_kernel: KernelPolicy = False) -> Callable:
     """Returns loss_fn(params, batch) running the GPipe schedule under
-    shard_map.  batch tokens: (n_micro, mb, S)."""
+    shard_map.  batch tokens: (n_micro, mb, S).  Call it under
+    ``jax.set_mesh(mesh)``: JAX 0.9 meshes have Explicit axes."""
     if cfg.family not in ("dense",):
         raise NotImplementedError("pipeline path covers the dense family "
                                   "(the paper's GPT-2 workload)")
@@ -102,18 +124,15 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
         rest_specs = jax.tree_util.tree_map(lambda _: P(), rest)
 
         @functools.partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(blk_specs, rest_specs, P(), P()),
-            out_specs=P(*axes),
-            check_rep=False)
+            out_specs=P(), check_vma=True)
         def run(blocks_l, rest_l, tok, lab):
             # blocks_l leaves: (1, L/ns, ...) — this stage's layers
             my = jax.tree_util.tree_map(lambda a: a[0], blocks_l)
             stage = jax.lax.axis_index(axes[0])
             if len(axes) == 2:
-                # psum(1, axis) == axis size; jax.lax.axis_size does not
-                # exist on the pinned jax (0.4.x)
-                stage = stage * jax.lax.psum(1, axes[1]) \
+                stage = stage * jax.lax.axis_size(axes[1]) \
                     + jax.lax.axis_index(axes[1])
             is_first = stage == 0
             is_last = stage == ns - 1
@@ -126,6 +145,9 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
                 return x
 
             def run_blocks(x):
+                # remat per block: the backward keeps each block's input for
+                # every tick, not its attention scores and MLP activations
+                @jax.checkpoint
                 def body(h, pl):
                     return _dense_block(cfg, pl, h, cfg.window), None
                 h, _ = jax.lax.scan(body, x, my)
@@ -133,9 +155,7 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
 
             def head_loss(x, i):
                 h = norm_apply(cfg.norm, rest_l["final_norm"], x)
-                logits = h @ rest_l["head"]["w"].astype(h.dtype) \
-                    if "head" in rest_l else \
-                    h @ rest_l["embed"]["table"].astype(h.dtype).T
+                logits = causal_lm._head(cfg, rest_l, h)
                 return cross_entropy(logits.astype(jnp.float32), lab[i])
 
             # Eq. 7 per-edge compression of the OUTGOING boundary.  With two
@@ -148,7 +168,8 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
                 if not slow_edges.any():
                     return x
                 k_comp = ratio_to_k(mb * S * d, float(ratios[slow_edges][0]))
-                flag = jnp.asarray(slow_edges)[jnp.minimum(stage, ns - 2)]
+                flag = functools.reduce(jnp.logical_or, [
+                    stage == s for s in np.flatnonzero(slow_edges)])
                 return jax.lax.cond(
                     flag,
                     lambda v: boundary_compress(v, k_comp, k_comp,
@@ -171,23 +192,15 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
                 nxt = jax.lax.ppermute(y, axes, perm_fwd)
                 return (nxt, loss_acc + loss_mb), None
 
-            (state, loss_acc), _ = jax.lax.scan(
-                tick, (state0, jnp.zeros((), jnp.float32)),
-                jnp.arange(total_ticks))
-            # one scalar shard per stage (only the last is non-zero); summed
-            # OUTSIDE the shard_map — transposing an in-map psum trips the
-            # pinned jax 0.4.x shard_map under check_rep=False
-            return loss_acc.reshape((1,) * len(axes))
+            # the carry differs per stage: type it as varying over the mesh
+            carry0 = jax.lax.pcast((state0, jnp.zeros((), jnp.float32)),
+                                   axes, to="varying")
+            (state, loss_acc), _ = jax.lax.scan(tick, carry0,
+                                                jnp.arange(total_ticks))
+            # only the last stage holds a non-zero loss
+            return jax.lax.psum(loss_acc, axes)
 
-        # remat the sharded region: grad-of-shard_map on the pinned jax
-        # 0.4.x mis-names scalar residuals (raises _SpecError); with
-        # checkpoint the only cross-boundary residuals are the inputs.
-        return jax.checkpoint(run)(blocks, rest, tokens, labels).sum() \
-            / n_micro
-
-    # checkpoint-of-shard_map requires a surrounding jit (eager closed_call
-    # under shard_map is unimplemented on jax 0.4.x)
-    loss_fn = jax.jit(loss_fn)
+        return run(blocks, rest, tokens, labels) / n_micro
 
     return loss_fn
 

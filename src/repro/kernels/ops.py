@@ -38,12 +38,16 @@ import numpy as np
 from . import ref as kref
 from . import topk_compress as tk
 
-INTERPRET = True  # CPU container; flip to False on real TPU
-
 Policy = Union[bool, str, None]
 
 #: policy values accepted by ``resolve_policy``
 POLICIES = (False, True, None, "off", "auto", "force")
+
+
+def off_tpu() -> bool:
+    """Pallas interpret mode exactly when no TPU backs the default device:
+    compiled kernels on the chip, the interpreter everywhere else."""
+    return jax.default_backend() != "tpu"
 
 
 def resolve_policy(policy: Policy) -> str:
@@ -52,11 +56,10 @@ def resolve_policy(policy: Policy) -> str:
     ``"interpret"`` (Pallas interpret mode), or ``"pallas"`` (compiled)."""
     if policy is None or policy is False or policy == "off":
         return "global"
-    on_tpu = jax.default_backend() == "tpu"
     if policy is True or policy == "force":
-        return "pallas" if on_tpu else "interpret"
+        return "interpret" if off_tpu() else "pallas"
     if policy == "auto":
-        return "pallas" if on_tpu else "xla"
+        return "xla" if off_tpu() else "pallas"
     raise ValueError(
         f"unknown kernel dispatch policy {policy!r}; expected one of "
         f"{POLICIES}")
@@ -77,7 +80,7 @@ def _blockwise_topk_mask(x, k_per_block, block, interpret):
 
 def blockwise_topk_mask(x: jax.Array, k_per_block: int,
                         block: int = tk.DEFAULT_BLOCK) -> jax.Array:
-    return _blockwise_topk_mask(x, k_per_block, block, INTERPRET)
+    return _blockwise_topk_mask(x, k_per_block, block, off_tpu())
 
 
 def topk_mask(x: jax.Array, k: int, block: int = tk.DEFAULT_BLOCK) -> jax.Array:
@@ -93,7 +96,7 @@ def _ef_topk(x, residual, k_per_block, block, interpret):
 
 def ef_topk(x: jax.Array, residual: jax.Array, k_per_block: int,
             block: int = tk.DEFAULT_BLOCK):
-    return _ef_topk(x, residual, k_per_block, block, INTERPRET)
+    return _ef_topk(x, residual, k_per_block, block, off_tpu())
 
 
 # ------------------------------------------------- fused encode / decode --
@@ -138,19 +141,19 @@ def encode_topk(x: jax.Array, k_per_block: int,
                 block: int = tk.DEFAULT_BLOCK, interpret=None):
     """Jitted fused wire encode (Pallas): (values, bitmap)."""
     return _encode_pallas(x, k_per_block, block,
-                          INTERPRET if interpret is None else interpret)
+                          off_tpu() if interpret is None else interpret)
 
 
 def decode_topk(values: jax.Array, bitmap: jax.Array,
                 shape: Tuple[int, ...], interpret=None):
     return _decode_pallas(values, bitmap, tuple(shape),
-                          INTERPRET if interpret is None else interpret)
+                          off_tpu() if interpret is None else interpret)
 
 
 def ef_encode_topk(x: jax.Array, residual: jax.Array, k_per_block: int,
                    block: int = tk.DEFAULT_BLOCK, interpret=None):
     return _ef_encode_pallas(x, residual, k_per_block, block,
-                             INTERPRET if interpret is None else interpret)
+                             off_tpu() if interpret is None else interpret)
 
 
 # ------------------------------------------------------- codec round trip --

@@ -62,10 +62,11 @@ def blockwise_topk_mask_ref(x: jax.Array, k_per_block: int,
 
 
 def _force_rounding(x: jax.Array) -> jax.Array:
-    """Pin storage-dtype rounding of a computed value (see the twin helper
-    in :mod:`repro.kernels.topk_compress`): under jit, XLA on CPU can keep a
-    bf16 sum in f32 on the path into the selection bitcast, diverging from
-    the eagerly-rounded value."""
+    """Pin storage-dtype rounding of a computed value: under jit, XLA on
+    CPU can keep a bf16 sum in f32 on the path into the selection bitcast,
+    diverging from the eagerly-rounded value.  (The kernels, which Mosaic
+    compiles without ``reduce_precision``, sum in f32 and round through an
+    ``astype`` round trip: the same value.)"""
     if x.dtype == jnp.bfloat16:
         return jax.lax.reduce_precision(x, 8, 7)
     if x.dtype == jnp.float16:

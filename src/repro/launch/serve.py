@@ -21,6 +21,7 @@ import argparse
 
 import jax
 
+from repro.launch.cache import enable_compile_cache
 from repro.obs import (FlightRecorder, MetricsRegistry, TraceRecorder,
                        slog, write_jsonl)
 
@@ -59,6 +60,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    enable_compile_cache()
     log = slog.get_logger("serve", metrics=MetricsRegistry(),
                           level=slog.level_from_args(args))
 

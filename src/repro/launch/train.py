@@ -18,12 +18,14 @@ into a :class:`repro.obs.metrics.MetricsRegistry` gauge.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Any, Callable, Dict, List
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from repro.launch.cache import enable_compile_cache
 from repro.obs import MetricsRegistry
 from repro.obs import slog
 
@@ -46,6 +48,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     slog.add_logging_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     metrics = MetricsRegistry()
     log = slog.get_logger("train", metrics=metrics,
                           level=slog.level_from_args(args))
@@ -65,7 +68,14 @@ def main() -> None:
     if args.mode == "gspmd":
         losses = _train_gspmd(cfg, ds, opt, args, log)
     else:
-        losses = _train_fusion(cfg, ds, opt, args, log)
+        job = fusion_job(cfg, opt, batch=args.batch, seq=args.seq,
+                         compress=args.compress, ratio=args.ratio,
+                         testbed=args.testbed)
+        log.event("fusion_plan", testbed=args.testbed, stages=job.n_stages,
+                  sim_iteration_s=job.sim.iteration_time,
+                  comm_mb=job.sim.comm_bytes / 1e6)
+        losses = train_fusion(job, ds, args.steps, log=log,
+                              log_every=args.log_every)
     log.event("train_done", mode=args.mode, steps=args.steps,
               final_loss=losses[-1], start_loss=losses[0])
 
@@ -81,10 +91,8 @@ def _train_gspmd(cfg, ds, opt, args, log):
     losses = []
     t0 = time.time()
     for i in range(args.steps):
-        b = ds.batch(args.batch, i)
-        batch = {"tokens": jnp.asarray(b["tokens"]),
-                 "labels": jnp.asarray(b["labels"])}
-        params, state, metrics = step_fn(params, state, batch)
+        params, state, metrics = step_fn(params, state,
+                                         device_batch(ds, args.batch, i))
         losses.append(float(metrics["loss"]))
         if i % args.log_every == 0:
             log.event("train_step", step=i, loss=losses[-1],
@@ -95,48 +103,83 @@ def _train_gspmd(cfg, ds, opt, args, log):
     return losses
 
 
-def _train_fusion(cfg, ds, opt, args, log):
+@dataclasses.dataclass
+class FusionJob:
+    """One RAD training job on the paper's decentralized runtime: the
+    OP-DAG and its per-op profile, the compression plan over its OP-Fence
+    schedule, the simulated iteration on the testbed, the RAD loss and
+    gradients the step applies, and the jitted step with its current params
+    and optimizer state (both donated to the step)."""
+
+    graph: Any
+    prof: Any
+    plan: Any
+    sim: Any
+    n_stages: int
+    batch: int
+    params: Any
+    opt_state: Any
+    loss_and_grad: Callable
+    step: Callable
+
+
+def fusion_job(cfg, opt, *, batch: int, seq: int, compress: str = "adatopk",
+               ratio: float = 100.0, testbed: int = 1) -> FusionJob:
+    """Plan (OP-Fence + AdaTopK on the testbed) and build the RAD step.
+    Boundary compression runs through the ``"auto"`` kernel policy:
+    compiled Pallas codec kernels on a TPU, the fused-XLA oracle with the
+    same semantics elsewhere."""
     from repro.core import (network, plan_adatopk, plan_none, plan_uniform,
                             schedule_opfence, simulate_iteration,
                             PipelineProgram, pipeline_loss_and_grad)
     from repro.models.opgraph_models import gpt_opgraph
 
-    graph = gpt_opgraph(cfg, args.batch, args.seq)
-    shapes = {"tokens": (args.batch, args.seq),
-              "labels": (args.batch, args.seq)}
+    graph = gpt_opgraph(cfg, batch, seq)
+    shapes = {"tokens": (batch, seq), "labels": (batch, seq)}
     prof = graph.annotate(shapes)
-    cluster = network.paper_testbed(args.testbed, seed=0)
+    cluster = network.paper_testbed(testbed, seed=0)
     sch = schedule_opfence(graph, prof, cluster)
     plan = {"none": lambda: plan_none(graph, sch.placement),
-            "uniform": lambda: plan_uniform(graph, sch.placement, args.ratio),
+            "uniform": lambda: plan_uniform(graph, sch.placement, ratio),
             "adatopk": lambda: plan_adatopk(graph, prof, cluster,
-                                            sch.placement, args.ratio)
-            }[args.compress]()
+                                            sch.placement, ratio)
+            }[compress]()
     sim = simulate_iteration(graph, prof, sch, cluster, plan, n_micro=2)
-    log.event("fusion_plan", testbed=args.testbed,
-              stages=len(sch.stage_devices()),
-              sim_iteration_s=sim.iteration_time,
-              comm_mb=sim.comm_bytes / 1e6)
     prog = PipelineProgram.build(graph, sch.pipeline_subdags(graph))
     params = graph.init(jax.random.PRNGKey(0), shapes)
-    state = opt.init(params)
 
-    @jax.jit
+    def loss_and_grad(params, batch):
+        return pipeline_loss_and_grad(prog, params, batch, plan,
+                                      use_kernel="auto")
+
     def step(params, state, batch):
-        loss, grads = pipeline_loss_and_grad(prog, params, batch, plan)
+        loss, grads = loss_and_grad(params, batch)
         params, state = opt.update(grads, state, params)
         return params, state, loss
 
+    return FusionJob(graph=graph, prof=prof, plan=plan, sim=sim,
+                     n_stages=len(sch.stage_devices()), batch=batch,
+                     params=params, opt_state=opt.init(params),
+                     loss_and_grad=loss_and_grad, step=jax.jit(step, donate_argnums=(0, 1)))
+
+
+def device_batch(ds, batch: int, i: int) -> Dict[str, jax.Array]:
+    b = ds.batch(batch, i)
+    return {"tokens": jnp.asarray(b["tokens"]),
+            "labels": jnp.asarray(b["labels"])}
+
+
+def train_fusion(job: FusionJob, ds, steps: int, log=None,
+                 log_every: int = 10) -> List[float]:
+    """Run ``steps`` RAD steps; the job keeps the updated params/state."""
     losses = []
-    for i in range(args.steps):
-        b = ds.batch(args.batch, i)
-        batch = {"tokens": jnp.asarray(b["tokens"]),
-                 "labels": jnp.asarray(b["labels"])}
-        params, state, loss = step(params, state, batch)
+    for i in range(steps):
+        job.params, job.opt_state, loss = job.step(
+            job.params, job.opt_state, device_batch(ds, job.batch, i))
         losses.append(float(loss))
-        if i % args.log_every == 0:
+        if log is not None and i % log_every == 0:
             log.event("train_step", step=i, loss=losses[-1],
-                      sim_wall_s=sim.iteration_time * (i + 1))
+                      sim_wall_s=job.sim.iteration_time * (i + 1))
     return losses
 
 

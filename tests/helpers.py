@@ -1,4 +1,8 @@
-"""Shared test fixtures: a small OP-DAG MLP chain (stand-in for a model)."""
+"""Shared test fixtures: a small OP-DAG MLP chain (stand-in for a model),
+and the repo-root ``chip_smoke.py`` loaded as a module."""
+import importlib.util
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -39,3 +43,12 @@ def mlp_chain(n_layers=6, d=16, batch=4, seed=0):
     inputs = {"x": jax.random.normal(k2, (batch, d)),
               "y": jax.random.normal(k3, (batch, d))}
     return g, shapes, params, inputs
+
+
+def load_chip_smoke():
+    """``chip_smoke.py`` (a script at the repo root, not a package module)."""
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

@@ -274,3 +274,15 @@ def test_lint_flags_kernel_dispatch_bypass():
     # outside the hot-path scopes the rule does not apply
     assert not [f for f in lint_source(src, "core/compression.py")
                 if f.code == "kernel-dispatch-bypass"]
+
+
+# ---------------------------------------------------------------- bench ----
+def test_kernel_bench_smoke():
+    import benchmarks.kernel_bench as bench
+    rows = []
+    result = bench.run(lambda *a: rows.append(a))["kernel"]
+    assert result["parity"] == 1.0
+    assert result["speedup"] > 0
+    structure = dict(kv.split("=") for kv in rows[-1][2].split(","))
+    assert structure["value_slots"] == "64"        # ceil(41 / 32) columns
+    assert int(structure["vmem_bytes"]) == result["vmem_bytes"]

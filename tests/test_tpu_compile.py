@@ -1,0 +1,97 @@
+"""The codec kernels compiled (not interpreted) for a described TPU v5e at
+the real boundary size — gpt2-xl, batch 4 x 1024 tokens x d_model 1600 —
+in f32 and bf16, and the codec as the four-chip GPipe step runs it (inside
+the ``check_vma`` ``shard_map`` over a described 2 x 2 mesh, at the pod
+edge's micro-batch boundary).  Nothing runs: the chip's compiler must
+accept each program, and the HLO must carry the kernel as a
+``tpu_custom_call``.
+
+The topology is described inside a module fixture (one process may hold
+the TPU library at a time, so nothing here touches it at import)."""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from helpers import load_chip_smoke
+from repro.kernels import ops
+from repro.kernels import topk_compress as tk
+
+SHAPE = (4, 1024, 1600)
+N = 4 * 1024 * 1600
+NB = -(-N // tk.DEFAULT_BLOCK)
+KPB = 41                       # ratio 100: ceil(ceil(N / 100) / NB)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip cannot be read back from the
+    # persistent cache without one: keep these compiles out of it
+    cache_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _kernel(name):
+    return {
+        "blockwise_topk_mask": lambda x: tk.blockwise_topk_mask(
+            x, KPB, interpret=False),
+        "ef_topk": lambda x, r: tk.ef_topk(x, r, KPB, interpret=False),
+        "encode_topk": lambda x: tk.encode_topk(x, KPB, interpret=False),
+        "ef_encode_topk": lambda x, r: tk.ef_encode_topk(
+            x, r, KPB, interpret=False),
+        "decode_topk": lambda v, m: tk.decode_topk(v, m, SHAPE,
+                                                   interpret=False),
+    }[name]
+
+
+def _args(name, dtype, sharding):
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    if name == "decode_topk":
+        return (sds((NB, KPB), dtype),
+                sds((NB, tk.DEFAULT_BLOCK // 32), jnp.uint32))
+    x = sds(SHAPE, dtype)
+    return (x, x) if name.startswith("ef_") else (x,)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", ["blockwise_topk_mask", "ef_topk",
+                                  "encode_topk", "ef_encode_topk",
+                                  "decode_topk"])
+def test_kernel_compiles_for_v5e(one_chip, name, dtype):
+    compiled = jax.jit(_kernel(name)).lower(
+        *_args(name, dtype, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_pipeline_codec_compiles_for_v5e_2x2(topo, monkeypatch):
+    """``chip_smoke.sharded_codec`` at the pod edge (4 micro-batches of
+    1 x 1024 x 1600, ratio 300), the policy steered to the compiled kernels
+    as it resolves on the chip."""
+    monkeypatch.setattr(ops, "off_tpu", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("pod", "model"))
+    x = jax.ShapeDtypeStruct((4, 1, 1024, 1600), jnp.float32,
+                             sharding=NamedSharding(mesh, P(("pod", "model"))))
+    k = -(-1024 * 1600 // 300)
+    compiled = jax.jit(load_chip_smoke().sharded_codec(mesh, k)).lower(
+        x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
