@@ -14,6 +14,11 @@ both directions of every cross-node edge — outside any stage's autodiff,
 exactly like the real transport (the consumer trains on the sparsified
 activation; the producer backpropagates the sparsified gradient).
 
+Each stage's forward and backward, and the codec on each compressed edge in
+each direction, run inside a ``jax.named_scope`` (:mod:`repro.obs.scopes`),
+so that a profiler trace of the jitted step attributes every device op to
+its stage or edge.
+
 ``pipeline_train_step`` with no compression is bit-identical to single-device
 ``jax.grad`` over :meth:`OpGraph.apply` (tested), which is the correctness
 contract of RAD.
@@ -31,6 +36,7 @@ import numpy as np
 from .compression import (CompressionPlan, KernelPolicy, compress_for_edge,
                           dense_payload_bytes, plan_none)
 from .opgraph import OpGraph, OpType, SubDag
+from ..obs.scopes import edge_scope, stage_scope
 from ..obs.trace import CAT_ENCODE
 
 
@@ -49,24 +55,29 @@ KernelCb = Callable[[int, bool, float, float], None]
 
 def _traced_compress(trace, name: str, track: str, backward: bool,
                      ratio: float, fn, kernel_cb: Optional[KernelCb] = None,
-                     stage: int = 0, dense_bytes: float = 0.0):
-    """Run one boundary compression, recording a wall-clock encode span when
-    tracing and a ``kernel_cb`` timing sample when instrumented.  The decode
-    half is fused into the same op (a kernel-dispatched topk_mask is
-    encode→decode of the wire format), so both cover the whole codec;
-    ``ratio<=1`` edges transport dense and record nothing."""
-    traced = trace is not None and getattr(trace, "enabled", False)
-    if ratio <= 1.0 or (not traced and kernel_cb is None):
+                     stage: int = 0, dense_bytes: float = 0.0, *,
+                     scope: str):
+    """Run one boundary compression inside the device scope ``scope``,
+    recording a wall-clock encode span when tracing and a ``kernel_cb``
+    timing sample when instrumented.  The decode half is fused into the
+    same op (a kernel-dispatched topk_mask is encode→decode of the wire
+    format), so both cover the whole codec; ``ratio<=1`` edges transport
+    dense and record nothing."""
+    if ratio <= 1.0:
         return fn()
-    t0 = time.perf_counter() if kernel_cb is not None else 0.0
-    if traced:
-        with trace.region(CAT_ENCODE, name, track,
-                          args={"ratio": ratio, "backward": backward}):
+    traced = trace is not None and getattr(trace, "enabled", False)
+    with jax.named_scope(scope):
+        if not traced and kernel_cb is None:
+            return fn()
+        t0 = time.perf_counter() if kernel_cb is not None else 0.0
+        if traced:
+            with trace.region(CAT_ENCODE, name, track,
+                              args={"ratio": ratio, "backward": backward}):
+                out = fn()
+                jax.block_until_ready(out)
+        else:
             out = fn()
             jax.block_until_ready(out)
-    else:
-        out = fn()
-        jax.block_until_ready(out)
     if kernel_cb is not None:
         kernel_cb(stage, backward, time.perf_counter() - t0, dense_bytes)
     return out
@@ -183,8 +194,10 @@ def pipeline_forward(prog: PipelineProgram, params: Params,
         ext = {a: mailbox[(a, si)] for a in sd.required_acti}
         received.append(ext)
         t0 = time.perf_counter() if timing_cb else 0.0
-        (sends, loss), vjp_fn = jax.vjp(
-            lambda p, e: fn(p, e, stage_inputs[si]), stage_params[si], ext)
+        with jax.named_scope(stage_scope(si, backward=False)):
+            (sends, loss), vjp_fn = jax.vjp(
+                lambda p, e: fn(p, e, stage_inputs[si]), stage_params[si],
+                ext)
         if timing_cb:
             # async dispatch returns before the compute runs — force it so
             # the sample measures stage execution, not dispatch overhead
@@ -206,7 +219,8 @@ def pipeline_forward(prog: PipelineProgram, params: Params,
                     lambda out=out, ratio=ratio: compress_for_edge(
                         out, ratio, use_kernel, compress_bwd),
                     kernel_cb=kernel_cb, stage=si,
-                    dense_bytes=dense_payload_bytes(out))
+                    dense_bytes=dense_payload_bytes(out),
+                    scope=edge_scope(a, cj, backward=False))
     return total_loss, vjps, received
 
 
@@ -237,7 +251,8 @@ def pipeline_backward(prog: PipelineProgram, vjps: List[Any],
             sends_cot[a] = g
         loss_cot = jnp.asarray(1.0, dtype=jnp.float32)
         t0 = time.perf_counter() if timing_cb else 0.0
-        p_cot, ext_cot = vjps[si]((sends_cot, loss_cot))
+        with jax.named_scope(stage_scope(si, backward=True)):
+            p_cot, ext_cot = vjps[si]((sends_cot, loss_cot))
         if timing_cb:
             jax.block_until_ready((p_cot, ext_cot))
             timing_cb(si, True, time.perf_counter() - t0)
@@ -252,7 +267,8 @@ def pipeline_backward(prog: PipelineProgram, vjps: List[Any],
                 lambda g=g, ratio=ratio: compress_for_edge(g, ratio,
                                                            use_kernel),
                 kernel_cb=kernel_cb, stage=si,
-                dense_bytes=dense_payload_bytes(g))
+                dense_bytes=dense_payload_bytes(g),
+                scope=edge_scope(a, si, backward=True))
             grad_mail[a] = grad_mail[a] + g if a in grad_mail else g
     return grads
 
@@ -343,8 +359,9 @@ def pipeline_loss_and_grad_ef(prog: PipelineProgram, params: Params,
         sd = prog.subdags[si]
         sends_cot = {a: grad_mail[a] for a in sd.send_acti}
         t0 = time.perf_counter() if timing_cb else 0.0
-        p_cot, ext_cot = vjps[si]((sends_cot,
-                                   jnp.asarray(1.0, jnp.float32)))
+        with jax.named_scope(stage_scope(si, backward=True)):
+            p_cot, ext_cot = vjps[si]((sends_cot,
+                                       jnp.asarray(1.0, jnp.float32)))
         if timing_cb:
             jax.block_until_ready((p_cot, ext_cot))
             timing_cb(si, True, time.perf_counter() - t0)
@@ -361,7 +378,8 @@ def pipeline_loss_and_grad_ef(prog: PipelineProgram, params: Params,
                     lambda corrected=corrected, k=k: topk_mask(
                         corrected, k, use_kernel=use_kernel),
                     kernel_cb=kernel_cb, stage=si,
-                    dense_bytes=dense_payload_bytes(g))
+                    dense_bytes=dense_payload_bytes(g),
+                    scope=edge_scope(a, si, backward=True))
                 new_ef[a] = corrected - sent
                 g = sent
             grad_mail[a] = grad_mail[a] + g if a in grad_mail else g

@@ -12,8 +12,13 @@ Two modes (DESIGN.md §4):
         --mode fusion --steps 50 --compress adatopk --ratio 100
 
 Reporting goes through :mod:`repro.obs.slog` — ``event k=v`` lines on
-stderr honoring ``--log-level``/``--quiet``, every numeric field mirrored
-into a :class:`repro.obs.metrics.MetricsRegistry` gauge.
+stderr honoring ``--log-level``/``--quiet``.
+
+Under a profiler, the RAD step's device ops carry the scopes of
+:mod:`repro.obs.scopes` (stage, boundary edge, ``optim``) and
+``train_fusion``'s steps show as host spans on the device trace's clock:
+``train.step`` holding ``train.batch``, ``train.dispatch`` and
+``train.fetch``.
 """
 from __future__ import annotations
 
@@ -26,8 +31,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.launch.cache import enable_compile_cache
-from repro.obs import MetricsRegistry
 from repro.obs import slog
+from repro.obs.scopes import OPTIM
+from repro.obs.trace import host_span
 
 
 def main() -> None:
@@ -49,9 +55,7 @@ def main() -> None:
     slog.add_logging_args(ap)
     args = ap.parse_args()
     enable_compile_cache()
-    metrics = MetricsRegistry()
-    log = slog.get_logger("train", metrics=metrics,
-                          level=slog.level_from_args(args))
+    log = slog.get_logger("train", level=slog.level_from_args(args))
 
     from repro.configs import resolve
     from repro.data import SyntheticLM
@@ -154,7 +158,8 @@ def fusion_job(cfg, opt, *, batch: int, seq: int, compress: str = "adatopk",
 
     def step(params, state, batch):
         loss, grads = loss_and_grad(params, batch)
-        params, state = opt.update(grads, state, params)
+        with jax.named_scope(OPTIM):
+            params, state = opt.update(grads, state, params)
         return params, state, loss
 
     return FusionJob(graph=graph, prof=prof, plan=plan, sim=sim,
@@ -171,12 +176,20 @@ def device_batch(ds, batch: int, i: int) -> Dict[str, jax.Array]:
 
 def train_fusion(job: FusionJob, ds, steps: int, log=None,
                  log_every: int = 10) -> List[float]:
-    """Run ``steps`` RAD steps; the job keeps the updated params/state."""
+    """Run ``steps`` RAD steps; the job keeps the updated params/state.
+    Each step is a ``train.step`` host span around its ``train.batch``,
+    ``train.dispatch`` and ``train.fetch`` (the loss read back to the
+    host)."""
     losses = []
     for i in range(steps):
-        job.params, job.opt_state, loss = job.step(
-            job.params, job.opt_state, device_batch(ds, job.batch, i))
-        losses.append(float(loss))
+        with host_span("train.step"):
+            with host_span("train.batch"):
+                batch = device_batch(ds, job.batch, i)
+            with host_span("train.dispatch"):
+                job.params, job.opt_state, loss = job.step(
+                    job.params, job.opt_state, batch)
+            with host_span("train.fetch"):
+                losses.append(float(loss))
         if log is not None and i % log_every == 0:
             log.event("train_step", step=i, loss=losses[-1],
                       sim_wall_s=job.sim.iteration_time * (i + 1))

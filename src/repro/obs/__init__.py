@@ -19,11 +19,14 @@ One spine, four artifacts:
 * :mod:`repro.obs.critpath` / :mod:`repro.obs.whatif` — critical-path
   bottleneck attribution over span logs and counterfactual re-pricing
   (imported explicitly, not re-exported: they pull in :mod:`repro.check`
-  and :mod:`repro.core` lazily).
+  and :mod:`repro.core` lazily);
+* :mod:`repro.obs.scopes`  — the RAD step's device scope names and the
+  scope an op_name names (imported explicitly).
 
-Everything here is dependency-free (stdlib + the repo's own dataclasses) and
-no-ops when disabled, so instrumented hot paths cost nothing in production
-runs that don't ask for a trace.
+Everything here is dependency-free (stdlib + the repo's own dataclasses;
+JAX only inside :func:`repro.obs.trace.host_span`) and no-ops when
+disabled, so instrumented hot paths cost nothing in production runs that
+don't ask for a trace.
 """
 from .bus import MetricsTelemetrySink, TelemetryBus
 from .export import (events_from_dicts, read_header, read_jsonl,

@@ -29,6 +29,10 @@ Categories (the ``cat`` field — what the report CLI groups by)::
     serve.replay            serving: KV-prefix replay onto a replacement
                             replica after a mid-session re-route
 
+Wall-clock regions (:meth:`TraceRecorder.region`) also open a
+:func:`host_span` of the same name, so that under ``jax.profiler`` they land
+in the profiler's host plane on the clock its device ops use.
+
 Guarantees the rest of the repo relies on:
 
 * **Disabled ⇒ no-op**: ``TraceRecorder(enabled=False)`` (or passing
@@ -93,6 +97,14 @@ class TraceEvent:
         if extra_args:
             args = {**(args or {}), **extra_args}
         return dataclasses.replace(self, ts=self.ts + dt, seq=seq, args=args)
+
+
+def host_span(name: str):
+    """A host span ``name`` in a running profiler's trace (a
+    ``jax.profiler.TraceAnnotation``), on the clock of the device ops; about
+    a microsecond when no profiler runs."""
+    import jax.profiler
+    return jax.profiler.TraceAnnotation(name)
 
 
 class _OpenSpan:
@@ -200,7 +212,8 @@ class TraceRecorder:
 
     def region(self, cat: str, name: str, track: str,
                args: Optional[Mapping[str, Any]] = None):
-        """Context manager recording a wall-clock span around its body."""
+        """Context manager recording a wall-clock span around its body, and
+        a :func:`host_span` ``name`` in a running profiler's trace."""
         if not self.enabled:
             return _NULL_REGION
         return _Region(self, cat, name, track, args)
@@ -255,7 +268,7 @@ class TraceRecorder:
 
 
 class _Region:
-    __slots__ = ("_rec", "_cat", "_name", "_track", "_args", "_t0")
+    __slots__ = ("_rec", "_cat", "_name", "_track", "_args", "_t0", "_span")
 
     def __init__(self, rec, cat, name, track, args):
         self._rec = rec
@@ -265,6 +278,8 @@ class _Region:
         self._args = args
 
     def __enter__(self):
+        self._span = host_span(self._name)
+        self._span.__enter__()
         self._t0 = self._rec.wall_now()
         return self
 
@@ -272,4 +287,5 @@ class _Region:
         self._rec._push(CLOCK_WALL, "X", self._cat, self._name, self._track,
                         self._t0, max(0.0, self._rec.wall_now() - self._t0),
                         self._args)
+        self._span.__exit__(*exc)
         return False

@@ -95,3 +95,81 @@ def test_pipeline_codec_compiles_for_v5e_2x2(topo, monkeypatch):
     compiled = jax.jit(load_chip_smoke().sharded_codec(mesh, k)).lower(
         x, x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def v5e_step(one_chip):
+    """The RAD AdaTopK step at smoke widths compiled for the chip with the
+    codec's kernels: (job, optimized HLO text, its scope table as the
+    benchmark reads it)."""
+    import unittest.mock
+    from chipbench.scope_reduce import instruction_scopes
+    from repro.configs import resolve
+    from repro.launch.train import fusion_job
+    from repro.obs.scopes import classify
+    from repro.optim import adamw
+
+    with unittest.mock.patch.object(ops, "off_tpu", lambda: False):
+        job = fusion_job(resolve("gpt2-xl").smoke, adamw(1e-3), batch=2,
+                         seq=64, compress="adatopk")
+
+        def sds(tree):
+            return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), tree)
+
+        batch = {k: jax.ShapeDtypeStruct((2, 64), jnp.int32,
+                                         sharding=one_chip)
+                 for k in ("tokens", "labels")}
+        hlo = job.step.lower(sds(job.params), sds(job.opt_state),
+                             batch).compile().as_text()
+    return job, hlo, instruction_scopes(hlo, classify)
+
+
+def test_rad_step_codec_kernels_fall_in_their_edge_scopes(v5e_step):
+    """Each kernel instruction (``_encode_pallas.N``, ``_decode_pallas.N``,
+    which ``codec_ms_per_step`` reads) falls in the codec scope of a
+    compressed edge, in both directions of each."""
+    from repro.obs import scopes as S
+    job, _, table = v5e_step
+    kernels = {n: s for n, s in table.items()
+               if n.startswith(("_encode_pallas", "_decode_pallas"))}
+    assert kernels and all(s is not None and s.kind == S.CODEC
+                           for s in kernels.values())
+    planned = {p for (p, _), r in job.plan.as_mapping().items() if r > 1.0}
+    assert {(s.where.split("/")[0], s.direction)
+            for s in kernels.values()} == {(p, d) for p in planned
+                                           for d in S.DIRECTIONS}
+
+
+def test_rad_step_matmuls_stay_in_their_stage_scopes(v5e_step):
+    """The chip's compiler fuses the optimizer's update of a weight into
+    the matmul that computes the weight's gradient.  Every matmul still
+    counts under the stage scope its own op_name names, so that no stage
+    can read less time than its FLOPs need at the chip's peak."""
+    import re
+    from chipbench.scope_reduce import _CALLS, _computations
+    from repro.obs.scopes import OPTIM, classify
+    _, hlo, table = v5e_step
+    comps = _computations(hlo)
+
+    def matmuls(text):
+        found = []
+        if re.search(r"\s(?:dot|convolution)\(", text):
+            op = re.search(r'op_name="([^"]*)"', text)
+            found.append(classify(op.group(1)) if op else None)
+        for c in _CALLS.findall(text):
+            for _, t in comps[c]:
+                found += matmuls(t)
+        return found
+
+    entry = next(c for c in comps if c.startswith("main"))
+    fused_with_optim = 0
+    for name, text in comps[entry]:
+        for scope in matmuls(text):
+            if scope is not None:
+                assert table[name] == scope, (name, scope, table[name])
+        body = "\n".join(t for c in _CALLS.findall(text)
+                         for _, t in comps[c])
+        if matmuls(text) and f"/{OPTIM}/" in body:
+            fused_with_optim += 1
+    assert fused_with_optim      # what makes the rule matter
