@@ -101,16 +101,17 @@ def run(csv_writer):
                f"encode={'ok' if parity else 'MISMATCH'},"
                f"roundtrip={'ok' if rt_ok else 'MISMATCH'}")
 
-    # structural stats of the compiled Pallas wire kernels (f32): per grid
-    # step the encode holds a word-major (32, block/32) input tile, the
-    # (32, ceil(k/32)) value slots and the (1, block/32) bitmap words; the
-    # decode adds a (32, block/32) scratch tile it expands the values in
-    slots = tk._WORD * tk._slot_columns(kpb)
-    vmem_bytes = 2 * block * 4 + slots * 4 + (block // tk._WORD) * 4
+    # structural stats of the compiled Pallas wire kernels (f32): a grid
+    # step owns 128 blocks, one per lane, and holds the (128, block) input
+    # slab, its (block, 128) keep-mask scratch, the (128, k) value slots
+    # and the (128, block/32) bitmap words
+    group = tk._LANE
+    grid = -(-nb // group)
+    vmem_bytes = 4 * group * (2 * block + kpb + block // tk._WORD)
     csv_writer("kernel_pallas_structure", 0.0,
                f"block={block},vmem_bytes={vmem_bytes},"
-               f"search_iters={tk._SEARCH_BITS},grid={nb},"
-               f"value_slots={slots}")
+               f"search_iters={tk._SEARCH_BITS},grid={grid},"
+               f"value_slots={kpb}")
     return {"kernel": {
         "t_unfused_us": t_unfused * 1e6,
         "t_fused_us": t_fused * 1e6,
@@ -118,5 +119,5 @@ def run(csv_writer):
         "speedup": speedup,
         "parity": float(parity and rt_ok),
         "vmem_bytes": float(vmem_bytes),
-        "grid": float(nb),
+        "grid": float(grid),
     }}

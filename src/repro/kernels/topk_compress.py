@@ -24,23 +24,31 @@ extra HBM round-trip.
 kernels: threshold search + mask-bitmap emission + packed-value compaction
 in one pallas_call (the "mask" encoding `wire_bytes` prices).  Unlike the
 dense kernels they are tie-capped — the wire has exactly k slots per block,
-so among threshold ties the first ``k - n_above`` in index order win.  The
-packed values fill whole word columns inside the kernel (``ceil(k/32)``
-of them); wrappers slice them back to k.
+so among threshold ties the first ``k - n_above`` in index order win.
 
-Layout.  Every grid step owns one block as a 2-D VMEM tile whose last two
-dimensions are the whole block, so the (8, 128) tiling rule holds for any
-block count.  The dense kernels view a block row-major as
-``(block/128, 128)``.  The wire kernels view it *word-major* as
-``(32, block/32)``: column ``w`` holds the 32 elements of bitmap word ``w``,
-so packing a word is a reduction over sublanes and unpacking is a shift by
-the sublane index.  Index order is then column-major, and the in-kernel
-prefix count (tie rank, packed slot) is a log-step shift-and-add along
-sublanes and then lanes (``pltpu.roll``).  Mosaic lowers no gather or
-scatter, so packing the kept values is a log-step compaction network: each
-kept element moves toward its slot by the set bits of its displacement,
-one roll-and-select step per bit (``log2(block)`` steps whatever k is),
-and decoding runs the same network in reverse.
+Layout.  The dense kernels give every grid step one block as a
+row-major ``(block/128, 128)`` tile.  The wire kernels give every grid step
+128 blocks, one per lane: the step reads a ``(128, block)`` slab of the
+``(nb, block)`` row-major view (no HBM transpose; the block count is padded
+to a multiple of 128 with zero blocks that are sliced away) and transposes
+it in VMEM to ``(block, 128)``, element ``j`` of block ``g`` at ``[j, g]``.
+Every step of the selection is then elementwise work over whole vregs with
+no cross-lane traffic: each search pass's count is a sum down the rows, the
+tie rank and slot index are a log-step shift-and-add down the rows
+(shifts of 8 rows or more move whole vregs).
+Bitmap word ``w`` of a block is rows ``32w .. 32w+31`` of its lane, read
+with strided loads.  Mosaic lowers no gather or scatter, so packing the
+kept values is a log-step compaction network: each kept element moves down
+its lane by the set bits of its displacement, one shift-and-select step per
+bit (``log2(block)`` steps whatever k is), and decoding runs the same
+network in reverse.  The packed values and the bitmap words go back to
+``(128, k)`` and ``(128, block/32)`` through small in-VMEM transposes, so
+the HBM wire tensors are ``values`` (nb, k) in index order and ``bitmap``
+(nb, block/32) uint32, LSB-first.  On a TPU v5e, at the gpt2-xl RAD step's
+logits edge (4 x 1024 x 50432 f32, 484 of every 4096 kept), an encode
+takes 18.8 ms of device time and a decode 14.2 ms, 0.37 and 0.28 us a
+block; with one block per grid step as a (32, 128) tile they took 339 and
+123 ms, every pass waiting on the one before.
 
 Kernels are validated in interpret mode against :mod:`repro.kernels.ref`
 (exact equality — same selection set by construction).
@@ -59,30 +67,39 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK = 4096        # elements per grid step (fits VMEM many times
                             # over; multiple of 8*128 VPU tiles)
 _SEARCH_BITS = 31           # full int32 positive range
-_LANE = 128                 # TPU lane width
+_LANE = 128                 # TPU lane width: wire blocks per grid step
 _WORD = 32                  # bits per bitmap word
+_EMPTY = -2 ** 31           # displacement of a row that holds no kept
+                            # element: no bit of a shift is set in it
+_VMEM_LIMIT = 48 * 2 ** 20  # a wire step's (block, 128) f32 working set
+                            # outgrows the default scoped VMEM (16 MiB on
+                            # a v5e; the logits edge's kernels need 32)
 
 
-def _count(mask: jax.Array) -> jax.Array:
-    """Number of true entries of a 2-D tile, as a (1, 1) int32."""
-    return jnp.sum(mask.astype(jnp.int32), axis=(0, 1), keepdims=True)
+def _count(mask: jax.Array, axes: Tuple[int, ...]) -> jax.Array:
+    """Number of true entries over ``axes``, kept as size-1 dims."""
+    return jnp.sum(mask.astype(jnp.int32), axis=axes, keepdims=True)
 
 
-def _kth_threshold_bits(mag_bits: jax.Array, k: int) -> jax.Array:
-    """Largest t such that count(mag_bits >= t) >= k (t=0 if k >= n), as a
-    (1, 1) int32.
+def _kth_threshold_bits(mag_bits: jax.Array, k: int,
+                        axes: Tuple[int, ...] = (0, 1)) -> jax.Array:
+    """Largest t such that count(mag_bits >= t) >= k over ``axes`` (t=0 if
+    k >= n), with ``axes`` kept as size-1 dims: (1, 1) for a dense tile,
+    (1, 128) for a wire slab, one threshold per lane.
 
     mag_bits: int32 bit patterns of non-negative floats (monotone in value).
     31 fixed iterations of compare+reduce — branch-free, VPU-only.
     """
-    lo = jnp.zeros((1, 1), jnp.int32)
-    hi = jnp.full((1, 1), 0x7F800000, jnp.int32)  # +inf bounds every
+    shape = tuple(1 if a in axes else n
+                  for a, n in enumerate(mag_bits.shape))
+    lo = jnp.zeros(shape, jnp.int32)
+    hi = jnp.full(shape, 0x7F800000, jnp.int32)  # +inf bounds every
     # magnitude (also keeps hi - lo + 1 inside int32 — 2^31-1 would overflow)
 
     def body(_, carry):
         lo, hi = carry
         mid = lo + ((hi - lo + 1) >> 1)
-        take = _count(mag_bits >= mid) >= k
+        take = _count(mag_bits >= mid, axes) >= k
         return (jnp.where(take, mid, lo), jnp.where(take, hi, mid - 1))
 
     lo, _ = jax.lax.fori_loop(0, _SEARCH_BITS, body, (lo, hi))
@@ -117,10 +134,12 @@ def _ef_topk_block_kernel(x_ref, r_ref, sent_ref, newr_ref, *, k: int):
     newr_ref[...] = (corrected - sent).astype(newr_ref.dtype)
 
 
-def _prep(x: jax.Array, block: int) -> Tuple[jax.Array, int, Tuple[int, ...]]:
+def _prep(x: jax.Array, block: int, group: int = 1
+          ) -> Tuple[jax.Array, int, Tuple[int, ...]]:
+    """(nb, block) zero-padded blocks, nb a multiple of ``group``."""
     flat = x.reshape(-1)
     n = flat.shape[0]
-    nb = -(-n // block)
+    nb = -(-n // (block * group)) * group
     flat = jnp.pad(flat, (0, nb * block - n)).reshape(nb, block)
     return flat, n, x.shape
 
@@ -188,193 +207,187 @@ def ef_topk(x: jax.Array, residual: jax.Array, k_per_block: int,
 
 
 # ---------------------------------------------------------------------------
-# Fused wire-encode / decode kernels
+# Fused wire-encode / decode kernels: 128 blocks per grid step, one per lane
 # ---------------------------------------------------------------------------
 
-def _shift_in(v: jax.Array, d: int, axis: int) -> jax.Array:
-    """``v`` moved ``d`` places toward higher indices along ``axis``, zeros
-    filling the vacated low end."""
-    idx = jax.lax.broadcasted_iota(jnp.int32, v.shape, axis)
-    return jnp.where(idx >= d, pltpu.roll(v, d, axis), 0)
+def _rows(shape) -> jax.Array:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, 0)
 
 
-def _prefix_count(m: jax.Array) -> jax.Array:
-    """Inclusive prefix sum of an int32 word-major (32, W) tile in index
-    order (column-major): log-step shift-and-add down each column, then an
-    exclusive scan of the column totals across lanes."""
-    c = m
+def _shift_rows(v: jax.Array, s: int, fill) -> jax.Array:
+    """Every lane moved ``s`` rows toward higher rows (``s < 0``: toward
+    lower ones), ``fill`` entering at the vacated end.  A multiple of 8
+    rows moves whole vregs."""
+    pad = jnp.full((abs(s),) + v.shape[1:], fill, v.dtype)
+    if s > 0:
+        return jnp.concatenate([pad, v[:-s]])
+    return jnp.concatenate([v[-s:], pad])
+
+
+def _prefix_rows(m: jax.Array) -> jax.Array:
+    """Inclusive prefix sum down the rows of every lane: log-step
+    shift-and-add."""
     d = 1
-    while d < c.shape[0]:
-        c = c + _shift_in(c, d, 0)
+    while d < m.shape[0]:
+        m = m + _shift_rows(m, d, 0)
         d *= 2
-    tot = c[-1:, :]
-    e = tot
-    d = 1
-    while d < e.shape[1]:
-        e = e + _shift_in(e, d, 1)
-        d *= 2
-    return c + (e - tot)
+    return m
 
 
-def _keep_capped_block(x32: jax.Array, k: int) -> jax.Array:
-    """Tie-capped keep-mask for one word-major tile: exactly k kept.
-    Everything strictly above the k-th largest bit pattern, plus the first
-    ``k - n_above`` threshold ties in index order."""
+def _select(x32: jax.Array, k: int) -> jax.Array:
+    """Tie-capped selection in every lane of a (block, 128) slab, as each
+    kept element's displacement to its packed slot (its row minus the
+    number kept before it), ``_EMPTY`` where nothing is kept.  Kept:
+    everything strictly above the k-th largest bit pattern, plus the first
+    ``k - n_above`` threshold ties in row order."""
     bits = _mag_bits(x32)
-    thr = _kth_threshold_bits(bits, k)
+    thr = _kth_threshold_bits(bits, k, axes=(0,))
     above = bits > thr
     tie = bits == thr
-    tie_rank = _prefix_count(tie.astype(jnp.int32))
-    return above | (tie & (tie_rank <= k - _count(above)))
+    # one scan counts both: elements above in the low half-word, ties in
+    # the high one (a block has fewer than 2^15 elements)
+    c = _prefix_rows(above.astype(jnp.int32) + (tie.astype(jnp.int32) << 16))
+    n_above = c & 0xFFFF
+    cap = k - n_above[-1:]                  # ties that still fit
+    ties = c >> 16
+    keep = above | (tie & (ties <= cap))
+    kept = n_above + jnp.minimum(ties, cap)  # kept up to and including j
+    return jnp.where(keep, _rows(x32.shape) + 1 - kept, _EMPTY)
 
 
-def _linear_index(shape) -> jax.Array:
-    """Index order of a word-major (32, W) tile: element (b, w) is w*32+b."""
-    return (jax.lax.broadcasted_iota(jnp.int32, shape, 1) * _WORD
-            + jax.lax.broadcasted_iota(jnp.int32, shape, 0))
-
-
-def _move(a: jax.Array, s: int, up: bool) -> jax.Array:
-    """Move every entry of a word-major tile ``s`` places along index order
-    (toward higher indices if ``up``), wrapping around the tile.  ``s`` is
-    a power of two: below 32 it crosses sublanes, carrying into the
-    neighbouring column; from 32 on it is a whole-column roll."""
-    S, W = a.shape
-    if s % S == 0:
-        m = s // S
-        return pltpu.roll(a, m if up else W - m, 1)
-    b = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
-    r0 = pltpu.roll(a, s if up else S - s, 0)
-    if W == 1:
-        return r0
-    r1 = pltpu.roll(r0, 1 if up else W - 1, 1)
-    return jnp.where(b >= s, r0, r1) if up else jnp.where(b < S - s, r0, r1)
-
-
-def _route(vals: jax.Array, disp: jax.Array, valid: jax.Array, up: bool):
-    """Move each valid entry ``disp`` places along index order — down
+def _route(disp: jax.Array, vals=None, up: bool = False):
+    """Move every kept entry ``disp`` rows along its lane — down
     (compaction) taking the bits of ``disp`` lowest first, or up
     (expansion) highest first, exactly undoing a compaction.  Down, the
-    kept elements stay in strictly increasing positions after every step
-    (their displacements never decrease along the index, and two of them
-    are at least as far apart as their displacements differ), so no step
-    lands one element on another.  Returns (vals, disp, valid) moved."""
-    nbits = max(1, (vals.size - 1).bit_length())
+    kept elements stay in strictly increasing rows after every step (their
+    displacements never decrease along the lane, and two of them are at
+    least as far apart as their displacements differ), so no step lands one
+    element on another: a row holds at most one of a lander and a stayer,
+    and ``_EMPTY`` is below every displacement.  ``vals`` travel with
+    ``disp``; rows an entry leaves keep a stale value.  Returns
+    (disp, vals)."""
+    nbits = max(1, (disp.shape[0] - 1).bit_length())
     for t in (reversed(range(nbits)) if up else range(nbits)):
-        s = 1 << t
-        mover = valid & (((disp >> t) & 1) == 1)
-        stay = valid & ~mover
-        landed = _move(mover.astype(jnp.int32), s, up) == 1
-        vals = jnp.where(landed, _move(vals, s, up),
-                         jnp.where(stay, vals, jnp.zeros_like(vals)))
-        disp = jnp.where(landed, _move(disp, s, up), disp)
-        valid = stay | landed
-    return vals, disp, valid
+        s = (1 << t) * (1 if up else -1)
+        mover = (disp & (1 << t)) != 0
+        moved = _shift_rows(jnp.where(mover, disp, _EMPTY), s, _EMPTY)
+        if vals is not None:
+            vals = jnp.where(moved != _EMPTY, _shift_rows(vals, s, 0), vals)
+        disp = jnp.maximum(moved, jnp.where(mover, _EMPTY, disp))
+    return disp, vals
 
 
-def _displacement(keep_i: jax.Array) -> jax.Array:
-    """How far each kept element sits from its packed slot: its index minus
-    the number of kept elements before it."""
-    return _linear_index(keep_i.shape) - (_prefix_count(keep_i) - 1)
+def _value_rows(k: int, block: int) -> int:
+    """Rows of a packed slab that hold k slots, in whole 128-row transposes."""
+    return min(block, -(-k // _LANE) * _LANE)
 
 
-def _emit_encoded(x32: jax.Array, keep: jax.Array, v_ref, m_ref):
-    """Write bitmap words (LSB-first, as int32 bit patterns) and the
-    index-order packed values: the kept elements compacted to the front of
-    the tile, whose first columns are the value slots."""
-    keep_i = keep.astype(jnp.int32)
-    shifts = jax.lax.broadcasted_iota(jnp.int32, keep_i.shape, 0)
-    m_ref[...] = jnp.sum(keep_i << shifts, axis=0, keepdims=True)
-    packed, _, _ = _route(x32, _displacement(keep_i), keep, up=False)
-    v_ref[...] = packed[:, :v_ref.shape[1]].astype(v_ref.dtype)
+def _encode_lanes(x32: jax.Array, k: int, v_ref, m_ref, keep_ref) -> None:
+    """Select, then write each lane's keep-mask to ``keep_ref``, its bitmap
+    words (LSB-first, as int32 bit patterns) and its kept values packed in
+    index order."""
+    disp = _select(x32, k)
+    keep_ref[...] = (disp != _EMPTY).astype(jnp.int32)
+    words = keep_ref[pl.ds(0, m_ref.shape[1], stride=_WORD), :]
+    for b in range(1, _WORD):
+        words = words | (keep_ref[pl.ds(b, m_ref.shape[1], stride=_WORD), :]
+                         << b)
+    m_ref[...] = words.T
+    _, packed = _route(disp, x32)
+    kr = _value_rows(k, x32.shape[0])
+    v_ref[...] = packed[:kr].T[:, :v_ref.shape[1]].astype(v_ref.dtype)
 
 
-def _encode_block_kernel(x_ref, v_ref, m_ref, *, k: int):
-    x = x_ref[...].astype(jnp.float32)
-    _emit_encoded(x, _keep_capped_block(x, k), v_ref, m_ref)
+def _encode_kernel(x_ref, v_ref, m_ref, keep_ref, *, k: int):
+    _encode_lanes(x_ref[...].astype(jnp.float32).T, k, v_ref, m_ref,
+                  keep_ref)
 
 
-def _ef_encode_block_kernel(x_ref, r_ref, v_ref, m_ref, newr_ref, *,
-                            k: int):
+def _ef_encode_kernel(x_ref, r_ref, v_ref, m_ref, newr_ref, keep_ref, *,
+                      k: int):
     corrected = _round_to(x_ref[...].astype(jnp.float32)
-                          + r_ref[...].astype(jnp.float32), x_ref.dtype)
-    keep = _keep_capped_block(corrected, k)
-    _emit_encoded(corrected, keep, v_ref, m_ref)
-    newr_ref[...] = jnp.where(keep, 0.0, corrected).astype(newr_ref.dtype)
+                          + r_ref[...].astype(jnp.float32), x_ref.dtype).T
+    _encode_lanes(corrected, k, v_ref, m_ref, keep_ref)
+    newr_ref[...] = jnp.where(keep_ref[...] == 1, 0.0, corrected).T.astype(
+        newr_ref.dtype)
 
 
-def _decode_block_kernel(v_ref, m_ref, o_ref, tile_ref):
-    """Expand the packed values back to their indices: the displacements
-    are compacted like the values were, then every value retraces its
+def _decode_kernel(v_ref, m_ref, o_ref, slots_ref, keep_ref):
+    """Expand the packed values back to their rows: the displacements are
+    compacted like the values were, then every value retraces its
     compaction in reverse."""
-    keep_i = (m_ref[...] >> jax.lax.broadcasted_iota(
-        jnp.int32, o_ref.shape, 0)) & 1
-    disp = _displacement(keep_i)
-    disp, _, slots = _route(disp, disp, keep_i == 1, up=False)
-    tile_ref[...] = jnp.zeros(tile_ref.shape, jnp.float32)
-    tile_ref[:, :v_ref.shape[1]] = v_ref[...].astype(jnp.float32)
-    dense, _, _ = _route(tile_ref[...], disp, slots, up=True)
-    o_ref[...] = dense.astype(o_ref.dtype)
+    words = m_ref[...].T
+    for b in range(_WORD):
+        keep_ref[pl.ds(b, words.shape[0], stride=_WORD), :] = (
+            (words >> b) & 1)
+    keep = keep_ref[...]
+    block, k = keep.shape[0], v_ref.shape[1]
+    disp = jnp.where(keep == 1, _rows(keep.shape) + 1 - _prefix_rows(keep),
+                     _EMPTY)
+    disp, _ = _route(disp)
+    slots_ref[...] = jnp.zeros(slots_ref.shape, jnp.float32)
+    slots_ref[:, :k] = v_ref[...].astype(jnp.float32)
+    vals = slots_ref[...].T
+    if vals.shape[0] < block:
+        vals = jnp.concatenate(
+            [vals, jnp.zeros((block - vals.shape[0], vals.shape[1]),
+                             jnp.float32)])
+    _, dense = _route(disp, vals, up=True)
+    o_ref[...] = jnp.where(keep == 1, dense, 0.0).T.astype(o_ref.dtype)
 
 
-def _slot_columns(k: int) -> int:
-    """Word-major columns that hold k packed values."""
-    return -(-k // _WORD)
+def _slab_spec(width: int) -> pl.BlockSpec:
+    """128 consecutive rows of an (nb, width) operand per grid step."""
+    return pl.BlockSpec((_LANE, width), lambda i: (i, 0))
 
 
-def _word_tiles(tiles: jax.Array) -> jax.Array:
-    """(nb, B) -> (nb, 32, B/32): column w holds bitmap word w's elements."""
+def _wire_call(kernel, ins, outs, scratch, interpret: bool):
+    """One pallas_call over slabs of 128 blocks (nb a multiple of 128)."""
+    return pl.pallas_call(
+        kernel,
+        grid=(ins[0].shape[0] // _LANE,),
+        in_specs=[_slab_spec(a.shape[1]) for a in ins],
+        out_specs=[_slab_spec(o.shape[1]) for o in outs],
+        out_shape=outs,
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(*ins)
+
+
+def _encode_outs(tiles: jax.Array, k: int):
     nb, block = tiles.shape
-    return tiles.reshape(nb, block // _WORD, _WORD).transpose(0, 2, 1)
+    return [_out((nb, k), tiles.dtype, tiles),
+            _out((nb, block // _WORD), jnp.int32, tiles)]
 
 
-def _from_word_tiles(tiles: jax.Array) -> jax.Array:
-    nb = tiles.shape[0]
-    return tiles.transpose(0, 2, 1).reshape(nb, -1)
-
-
-def _wire_specs(kc: int, W: int):
-    return [_tile_spec((_WORD, kc)), _tile_spec((1, W))]
-
-
-def _wire_shapes(tiles: jax.Array, kc: int, W: int):
-    nb = tiles.shape[0]
-    return [_out((nb, _WORD, kc), tiles.dtype, tiles),
-            _out((nb, 1, W), jnp.int32, tiles)]
-
-
-def _to_wire(values: jax.Array, words: jax.Array, k: int
+def _to_wire(values: jax.Array, words: jax.Array, nb: int
              ) -> Tuple[jax.Array, jax.Array]:
-    return (_from_word_tiles(values)[:, :k],
-            jax.lax.bitcast_convert_type(words[:, 0, :], jnp.uint32))
+    return values[:nb], jax.lax.bitcast_convert_type(words[:nb], jnp.uint32)
 
 
-def _check_block(block: int) -> None:
-    if block % _WORD:
-        raise ValueError(f"block must be a multiple of 32, got {block}")
+def _wire_k(k_per_block: int, block: int) -> int:
+    """Slots per block; the block must fill whole bitmap words and keep
+    its counts inside a half-word."""
+    if block % _WORD or not 0 < block < 2 ** 15:
+        raise ValueError(
+            f"block must be a multiple of 32 below 2^15, got {block}")
+    return int(min(max(k_per_block, 1), block))
 
 
 def encode_topk(x: jax.Array, k_per_block: int, block: int = DEFAULT_BLOCK,
                 interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
     """Fused wire encode: (values (nb, k) in index order, bitmap (nb, B/32)
-    uint32) in one pallas_call per tile.  Exactly k slots per block."""
+    uint32) in one pallas_call.  Exactly k slots per block."""
     if x.dtype not in (jnp.float32, jnp.bfloat16, jnp.float16):
         raise TypeError(f"unsupported dtype {x.dtype}")
-    _check_block(block)
-    k = int(min(max(k_per_block, 1), block))
-    kc, W = _slot_columns(k), block // _WORD
-    tiles, _, _ = _prep(x, block)
-    tiles = _word_tiles(tiles)
-    nb = tiles.shape[0]
-    values, words = pl.pallas_call(
-        functools.partial(_encode_block_kernel, k=k),
-        grid=(nb,),
-        in_specs=[_tile_spec((_WORD, W))],
-        out_specs=_wire_specs(kc, W),
-        out_shape=_wire_shapes(tiles, kc, W),
-        interpret=interpret,
-    )(tiles)
-    return _to_wire(values, words, k)
+    k = _wire_k(k_per_block, block)
+    tiles, n, _ = _prep(x, block, _LANE)
+    values, words = _wire_call(
+        functools.partial(_encode_kernel, k=k), [tiles],
+        _encode_outs(tiles, k), [pltpu.VMEM((block, _LANE), jnp.int32)],
+        interpret)
+    return _to_wire(values, words, -(-n // block))
 
 
 def ef_encode_topk(x: jax.Array, residual: jax.Array, k_per_block: int,
@@ -382,44 +395,29 @@ def ef_encode_topk(x: jax.Array, residual: jax.Array, k_per_block: int,
                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Fused error-feedback wire encode: compress (x + residual) and emit
     (values, bitmap, new_residual) — residual update in the same kernel."""
-    _check_block(block)
-    k = int(min(max(k_per_block, 1), block))
-    kc, W = _slot_columns(k), block // _WORD
-    tiles, n, shape = _prep(x, block)
-    rtiles, _, _ = _prep(residual, block)
-    tiles, rtiles = _word_tiles(tiles), _word_tiles(rtiles)
-    nb = tiles.shape[0]
-    tile_spec = _tile_spec((_WORD, W))
-    values, words, newr = pl.pallas_call(
-        functools.partial(_ef_encode_block_kernel, k=k),
-        grid=(nb,),
-        in_specs=[tile_spec, tile_spec],
-        out_specs=_wire_specs(kc, W) + [tile_spec],
-        out_shape=_wire_shapes(tiles, kc, W)
-        + [_out(tiles.shape, tiles.dtype, tiles)],
-        interpret=interpret,
-    )(tiles, rtiles)
-    values, bitmap = _to_wire(values, words, k)
-    newr = _from_word_tiles(newr).reshape(-1)[:n].reshape(shape)
-    return values, bitmap, newr
+    k = _wire_k(k_per_block, block)
+    tiles, n, shape = _prep(x, block, _LANE)
+    rtiles, _, _ = _prep(residual, block, _LANE)
+    values, words, newr = _wire_call(
+        functools.partial(_ef_encode_kernel, k=k), [tiles, rtiles],
+        _encode_outs(tiles, k) + [_out(tiles.shape, tiles.dtype, tiles)],
+        [pltpu.VMEM((block, _LANE), jnp.int32)], interpret)
+    values, bitmap = _to_wire(values, words, -(-n // block))
+    return values, bitmap, newr.reshape(-1)[:n].reshape(shape)
 
 
 def decode_topk(values: jax.Array, bitmap: jax.Array,
                 shape: Tuple[int, ...], interpret: bool = True) -> jax.Array:
     """Inverse of :func:`encode_topk`: dense tensor of ``shape``."""
     nb, k = values.shape
-    W = bitmap.shape[1]
-    kc = _slot_columns(k)
-    values = _word_tiles(jnp.pad(values, ((0, 0), (0, kc * _WORD - k))))
-    words = jax.lax.bitcast_convert_type(bitmap, jnp.int32)
-    dense = pl.pallas_call(
-        _decode_block_kernel,
-        grid=(nb,),
-        in_specs=_wire_specs(kc, W),
-        out_specs=_tile_spec((_WORD, W)),
-        out_shape=_out((nb, _WORD, W), values.dtype, values),
-        scratch_shapes=[pltpu.VMEM((_WORD, W), jnp.float32)],
-        interpret=interpret,
-    )(values, words.reshape(nb, 1, W))
+    block = bitmap.shape[1] * _WORD
+    pad = ((0, -nb % _LANE), (0, 0))
+    values = jnp.pad(values, pad)
+    words = jnp.pad(jax.lax.bitcast_convert_type(bitmap, jnp.int32), pad)
+    (dense,) = _wire_call(
+        _decode_kernel, [values, words],
+        [_out((values.shape[0], block), values.dtype, values)],
+        [pltpu.VMEM((_LANE, _value_rows(k, block)), jnp.float32),
+         pltpu.VMEM((block, _LANE), jnp.int32)], interpret)
     n = int(np.prod(shape))
-    return _from_word_tiles(dense).reshape(-1)[:n].reshape(shape)
+    return dense.reshape(-1)[:n].reshape(shape)

@@ -284,5 +284,5 @@ def test_kernel_bench_smoke():
     assert result["parity"] == 1.0
     assert result["speedup"] > 0
     structure = dict(kv.split("=") for kv in rows[-1][2].split(","))
-    assert structure["value_slots"] == "64"        # ceil(41 / 32) columns
+    assert structure["value_slots"] == "41"        # exactly k per block
     assert int(structure["vmem_bytes"]) == result["vmem_bytes"]

@@ -75,49 +75,79 @@ def test_zero_input_keeps_everything_zero():
 # ------------------------------------------------- fused encode / decode --
 
 ENC_CASES = [((4096,), 11), ((5000,), 13), ((33, 257), 17), ((64,), 9)]
+# (shape, k per block, block) at the wire kernels' grouping of 128 blocks
+# per grid step, one per lane: fewer blocks than a group, a last group
+# padded with zero blocks, k = 1, 14, 484 and the whole block; the input's
+# first block is all zeros and its second all ties, next to random ones
+GROUP_CASES = [((3 * 4096 + 100,), 484, 4096), ((131 * 512 - 9,), 14, 512),
+               ((150 * 32,), 1, 32), ((70 * 32,), 32, 32)]
 
 
-@pytest.mark.parametrize("shape,kpb", ENC_CASES)
+def _enc_cases(blocks):
+    """ENC_CASES over ``blocks`` (random inputs), then GROUP_CASES (mixed
+    inputs): (shape, kpb, blocks, mixed)."""
+    return ([pytest.param(s, k, blocks, False, id=f"shape{i}-{k}")
+             for i, (s, k) in enumerate(ENC_CASES)]
+            + [pytest.param(s, k, (b,), True,
+                            id=f"n{int(np.prod(s))}-k{k}-block{b}")
+               for s, k, b in GROUP_CASES])
+
+
+def _enc_input(rng, shape, dtype, block, mixed):
+    flat = rng.standard_normal(int(np.prod(shape)))
+    if mixed:
+        flat[:block] = 0.0
+        flat[block:2 * block] = np.where(rng.random(block) < 0.5, -1.5, 1.5)
+    return jnp.asarray(flat.reshape(shape), dtype=dtype)
+
+
+@pytest.mark.parametrize("shape,kpb,blocks,mixed", _enc_cases((32, 512)))
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_encode_kernel_matches_oracle(shape, kpb, dtype):
+def test_encode_kernel_matches_oracle(shape, kpb, blocks, mixed, dtype):
     rng = np.random.default_rng(hash((shape, kpb)) % 2**32)
-    x = jnp.asarray(rng.standard_normal(shape), dtype=dtype)
-    for block in (32, 512):
+    x = _enc_input(rng, shape, dtype, blocks[0], mixed)
+    for block in blocks:
         v_k, m_k = tk.encode_topk(x, kpb, block=block, interpret=True)
         v_r, m_r = ref.encode_topk_ref(x, kpb, block=block)
         np.testing.assert_array_equal(np.asarray(v_k), np.asarray(v_r))
         np.testing.assert_array_equal(np.asarray(m_k), np.asarray(m_r))
 
 
-@pytest.mark.parametrize("shape,kpb", ENC_CASES)
+@pytest.mark.parametrize("shape,kpb,blocks,mixed", _enc_cases((512,)))
 @pytest.mark.parametrize("dtype", DTYPES)
-def test_ef_encode_kernel_matches_oracle(shape, kpb, dtype):
+def test_ef_encode_kernel_matches_oracle(shape, kpb, blocks, mixed, dtype):
     rng = np.random.default_rng(hash((shape, kpb, 1)) % 2**32)
-    x = jnp.asarray(rng.standard_normal(shape), dtype=dtype)
+    (block,) = blocks
+    x = _enc_input(rng, shape, dtype, block, mixed)
     r = jnp.asarray(rng.standard_normal(shape) * 0.1, dtype=dtype)
-    v_k, m_k, nr_k = tk.ef_encode_topk(x, r, kpb, block=512, interpret=True)
-    v_r, m_r, nr_r = ref.ef_encode_topk_ref(x, r, kpb, block=512)
+    v_k, m_k, nr_k = tk.ef_encode_topk(x, r, kpb, block=block,
+                                       interpret=True)
+    v_r, m_r, nr_r = ref.ef_encode_topk_ref(x, r, kpb, block=block)
     np.testing.assert_array_equal(np.asarray(v_k), np.asarray(v_r))
     np.testing.assert_array_equal(np.asarray(m_k), np.asarray(m_r))
     np.testing.assert_array_equal(np.asarray(nr_k), np.asarray(nr_r))
 
 
-@pytest.mark.parametrize("shape,kpb", ENC_CASES)
-def test_encode_decode_round_trip(shape, kpb):
+@pytest.mark.parametrize("shape,kpb,blocks,mixed", _enc_cases((512,)))
+def test_encode_decode_round_trip(shape, kpb, blocks, mixed):
     """decode(encode(x)) reconstructs exactly the kept elements — i.e. the
-    tie-capped keep set as a dense tensor — for kernel and oracle alike."""
-    rng = np.random.default_rng(hash(shape) % 2**32)
-    x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    v, m = tk.encode_topk(x, kpb, block=512, interpret=True)
-    dense_k = tk.decode_topk(v, m, x.shape, interpret=True)
-    dense_r = ref.decode_topk_ref(*ref.encode_topk_ref(x, kpb, block=512),
-                                  shape=x.shape)
-    np.testing.assert_array_equal(np.asarray(dense_k), np.asarray(dense_r))
-    # every reconstructed nonzero matches the input at its position
-    got = np.asarray(dense_k)
-    want = np.asarray(x)
-    nz = got != 0
-    np.testing.assert_array_equal(got[nz], want[nz])
+    tie-capped keep set as a dense tensor — for kernel and oracle alike, in
+    every input dtype."""
+    (block,) = blocks
+    for dtype in DTYPES:
+        rng = np.random.default_rng(hash(shape) % 2**32)
+        x = _enc_input(rng, shape, dtype, block, mixed)
+        v, m = tk.encode_topk(x, kpb, block=block, interpret=True)
+        dense_k = tk.decode_topk(v, m, x.shape, interpret=True)
+        dense_r = ref.decode_topk_ref(
+            *ref.encode_topk_ref(x, kpb, block=block), shape=x.shape)
+        np.testing.assert_array_equal(np.asarray(dense_k),
+                                      np.asarray(dense_r))
+        # every reconstructed nonzero matches the input at its position
+        got = np.asarray(dense_k)
+        want = np.asarray(x)
+        nz = got != 0
+        np.testing.assert_array_equal(got[nz], want[nz])
 
 
 def test_encode_all_zeros_and_ties():

@@ -1,10 +1,11 @@
 """The codec kernels compiled (not interpreted) for a described TPU v5e at
 the real boundary size — gpt2-xl, batch 4 x 1024 tokens x d_model 1600 —
-in f32 and bf16, and the codec as the four-chip GPipe step runs it (inside
-the ``check_vma`` ``shard_map`` over a described 2 x 2 mesh, at the pod
-edge's micro-batch boundary).  Nothing runs: the chip's compiler must
-accept each program, and the HLO must carry the kernel as a
-``tpu_custom_call``.
+in f32 and bf16, the wire kernels also at the logits edge's own size
+(4 x 1024 x 50432 padded vocabulary, f32, 484 of every 4096 kept), and
+the codec as the four-chip GPipe step runs it (inside the ``check_vma``
+``shard_map`` over a described 2 x 2 mesh, at the pod edge's micro-batch
+boundary).  Nothing runs: the chip's compiler must accept each program,
+and the HLO must carry the kernel as a ``tpu_custom_call``.
 
 The topology is described inside a module fixture (one process may hold
 the TPU library at a time, so nothing here touches it at import)."""
@@ -22,9 +23,9 @@ from repro.kernels import ops
 from repro.kernels import topk_compress as tk
 
 SHAPE = (4, 1024, 1600)
-N = 4 * 1024 * 1600
-NB = -(-N // tk.DEFAULT_BLOCK)
-KPB = 41                       # ratio 100: ceil(ceil(N / 100) / NB)
+KPB = 41                       # ratio 100: ceil(ceil(n / 100) / blocks)
+LOGITS = (4, 1024, 50432)      # the RAD step's compressed logits edge
+LOGITS_KPB = 484
 
 
 @pytest.fixture(scope="module")
@@ -49,37 +50,43 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _kernel(name):
+def _kernel(name, shape, kpb):
     return {
         "blockwise_topk_mask": lambda x: tk.blockwise_topk_mask(
-            x, KPB, interpret=False),
-        "ef_topk": lambda x, r: tk.ef_topk(x, r, KPB, interpret=False),
-        "encode_topk": lambda x: tk.encode_topk(x, KPB, interpret=False),
+            x, kpb, interpret=False),
+        "ef_topk": lambda x, r: tk.ef_topk(x, r, kpb, interpret=False),
+        "encode_topk": lambda x: tk.encode_topk(x, kpb, interpret=False),
         "ef_encode_topk": lambda x, r: tk.ef_encode_topk(
-            x, r, KPB, interpret=False),
-        "decode_topk": lambda v, m: tk.decode_topk(v, m, SHAPE,
+            x, r, kpb, interpret=False),
+        "decode_topk": lambda v, m: tk.decode_topk(v, m, shape,
                                                    interpret=False),
     }[name]
 
 
-def _args(name, dtype, sharding):
-    def sds(shape, dt):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+def _args(name, dtype, shape, kpb, sharding):
+    def sds(s, dt):
+        return jax.ShapeDtypeStruct(s, dt, sharding=sharding)
     if name == "decode_topk":
-        return (sds((NB, KPB), dtype),
-                sds((NB, tk.DEFAULT_BLOCK // 32), jnp.uint32))
-    x = sds(SHAPE, dtype)
+        nb = -(-int(np.prod(shape)) // tk.DEFAULT_BLOCK)
+        return (sds((nb, kpb), dtype),
+                sds((nb, tk.DEFAULT_BLOCK // 32), jnp.uint32))
+    x = sds(shape, dtype)
     return (x, x) if name.startswith("ef_") else (x,)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("name", ["blockwise_topk_mask", "ef_topk",
-                                  "encode_topk", "ef_encode_topk",
-                                  "decode_topk"])
-def test_kernel_compiles_for_v5e(one_chip, name, dtype):
-    compiled = jax.jit(_kernel(name)).lower(
-        *_args(name, dtype, one_chip)).compile()
+KERNEL_CASES = [
+    pytest.param(name, dtype, SHAPE, KPB, id=f"{name}-{tag}")
+    for name in ("blockwise_topk_mask", "ef_topk", "encode_topk",
+                 "ef_encode_topk", "decode_topk")
+    for dtype, tag in ((jnp.float32, "f32"), (jnp.bfloat16, "bf16"))
+] + [pytest.param(name, jnp.float32, LOGITS, LOGITS_KPB, id=f"{name}-logits")
+     for name in ("encode_topk", "decode_topk")]
+
+
+@pytest.mark.parametrize("name,dtype,shape,kpb", KERNEL_CASES)
+def test_kernel_compiles_for_v5e(one_chip, name, dtype, shape, kpb):
+    compiled = jax.jit(_kernel(name, shape, kpb)).lower(
+        *_args(name, dtype, shape, kpb, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
