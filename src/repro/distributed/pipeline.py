@@ -20,6 +20,12 @@ constructs:
   permute, compressed by the same per-edge plan (``boundary_compress`` is a
   custom_vjp whose backward sparsifies the cotangent).
 
+Each part of a tick runs in a ``jax.named_scope`` of
+:mod:`repro.obs.scopes` (``pipe/embed``, ``pipe/blocks``, ``pipe/head``,
+``pipe/edge/{after}``, ``pipe/exchange``), and the tick loop in
+``pipe/ticks``, so a device trace splits the step by part; scopes change
+metadata only.
+
 Supports the dense/GPT-2 family (homogeneous blocks — the paper's own
 workload).  n_layers must divide evenly into stages.
 """
@@ -39,6 +45,8 @@ from repro.core.compression import (KernelPolicy, boundary_compress,
 from repro.models import causal_lm
 from repro.models.causal_lm import _dense_block
 from repro.models.layers import cross_entropy, embed, norm_apply
+from repro.obs.scopes import (BLOCKS, EMBED, EXCHANGE, HEAD, TICKS,
+                              pipe_edge_scope, pipe_scope)
 
 
 def stage_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -137,6 +145,7 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
             is_first = stage == 0
             is_last = stage == ns - 1
 
+            @jax.named_scope(pipe_scope(EMBED))
             def embed_mb(i):
                 x = embed(rest_l["embed"], tok[i], cfg.dtype)
                 if cfg.rope_fraction == 0.0:
@@ -144,6 +153,7 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
                                   cfg.dtype)[None]
                 return x
 
+            @jax.named_scope(pipe_scope(BLOCKS))
             def run_blocks(x):
                 # remat per block: the backward keeps each block's input for
                 # every tick, not its attention scores and MLP activations
@@ -153,6 +163,7 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
                 h, _ = jax.lax.scan(body, x, my)
                 return h
 
+            @jax.named_scope(pipe_scope(HEAD))
             def head_loss(x, i):
                 h = norm_apply(cfg.norm, rest_l["final_norm"], x)
                 logits = causal_lm._head(cfg, rest_l, h)
@@ -160,21 +171,29 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
 
             # Eq. 7 per-edge compression of the OUTGOING boundary.  With two
             # bandwidth tiers every slow (pod-crossing) edge shares one
-            # ratio 3r, so one static k suffices; whether THIS stage's edge
-            # is slow is a traced predicate (lax.cond — one branch runs).
-            slow_edges = ratios > 1.0
+            # ratio 3r, so one static k suffices; which slow edge, if any,
+            # leaves THIS stage is a traced index (lax.switch — one branch
+            # runs), each edge's codec in its own scope.
+            slow_edges = np.flatnonzero(ratios > 1.0)
 
             def compress_boundary(x):
-                if not slow_edges.any():
+                if not slow_edges.size:
                     return x
-                k_comp = ratio_to_k(mb * S * d, float(ratios[slow_edges][0]))
-                flag = functools.reduce(jnp.logical_or, [
-                    stage == s for s in np.flatnonzero(slow_edges)])
-                return jax.lax.cond(
-                    flag,
-                    lambda v: boundary_compress(v, k_comp, k_comp,
-                                                use_kernel),
-                    lambda v: v, x)
+                k_comp = ratio_to_k(mb * S * d, float(ratios[slow_edges[0]]))
+
+                def edge(s):
+                    after = f"block_{(s + 1) * (cfg.n_layers // ns) - 1}"
+
+                    @jax.named_scope(pipe_edge_scope(after))
+                    def codec(v):
+                        return boundary_compress(v, k_comp, k_comp,
+                                                 use_kernel)
+                    return codec
+
+                branch = sum((stage == s) * (j + 1)
+                             for j, s in enumerate(slow_edges))
+                return jax.lax.switch(
+                    branch, [lambda v: v] + [edge(s) for s in slow_edges], x)
 
             total_ticks = n_micro + ns - 1
             state0 = jnp.zeros((mb, S, d), cfg.dtype)   # incoming boundary
@@ -189,14 +208,16 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
                 loss_mb = jnp.where(is_last & active,
                                     head_loss(y, mb_safe), 0.0)
                 y = compress_boundary(y)
-                nxt = jax.lax.ppermute(y, axes, perm_fwd)
+                with jax.named_scope(pipe_scope(EXCHANGE)):
+                    nxt = jax.lax.ppermute(y, axes, perm_fwd)
                 return (nxt, loss_acc + loss_mb), None
 
             # the carry differs per stage: type it as varying over the mesh
             carry0 = jax.lax.pcast((state0, jnp.zeros((), jnp.float32)),
                                    axes, to="varying")
-            (state, loss_acc), _ = jax.lax.scan(tick, carry0,
-                                                jnp.arange(total_ticks))
+            with jax.named_scope(pipe_scope(TICKS)):
+                (state, loss_acc), _ = jax.lax.scan(tick, carry0,
+                                                    jnp.arange(total_ticks))
             # only the last stage holds a non-zero loss
             return jax.lax.psum(loss_acc, axes)
 
@@ -206,8 +227,10 @@ def make_pipeline_train_fn(cfg: ModelCfg, mesh: Mesh, n_micro: int,
 
 
 def make_pipeline_train_step(cfg: ModelCfg, mesh: Mesh, optimizer,
-                             n_micro: int, base_ratio: float = 1.0):
-    loss_fn = make_pipeline_train_fn(cfg, mesh, n_micro, base_ratio)
+                             n_micro: int, base_ratio: float = 1.0,
+                             use_kernel: KernelPolicy = False):
+    loss_fn = make_pipeline_train_fn(cfg, mesh, n_micro, base_ratio,
+                                     use_kernel)
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 
 from repro.launch.cache import enable_compile_cache
 from repro.obs import slog
-from repro.obs.scopes import OPTIM
 from repro.obs.trace import host_span
 
 
@@ -158,8 +157,7 @@ def fusion_job(cfg, opt, *, batch: int, seq: int, compress: str = "adatopk",
 
     def step(params, state, batch):
         loss, grads = loss_and_grad(params, batch)
-        with jax.named_scope(OPTIM):
-            params, state = opt.update(grads, state, params)
+        params, state = opt.update(grads, state, params)
         return params, state, loss
 
     return FusionJob(graph=graph, prof=prof, plan=plan, sim=sim,

@@ -1,6 +1,7 @@
 """Optimizers as pure pytree transforms (no optax dependency).
 
-Each optimizer is an (init, update) pair packaged in :class:`Optimizer`;
+Each optimizer is an (init, update) pair packaged in :class:`Optimizer`,
+its update traced inside the ``optim`` device scope (:mod:`repro.obs.scopes`);
 state and params are arbitrary pytrees, so the same code drives the GSPMD
 train step (sharded state), the FusionLLM decentralized runtime (per-
 CompNode sub-trees — the paper's per-OP "Update" stage, §3.3), and unit
@@ -9,11 +10,14 @@ tests.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.obs.scopes import OPTIM
 
 
 class OptState(NamedTuple):
@@ -30,6 +34,15 @@ class Optimizer:
 
 def _tmap(f, *trees):
     return jax.tree_util.tree_map(f, *trees)
+
+
+def _scoped(update):
+    """``update`` inside the optimizer's device scope."""
+    @functools.wraps(update)
+    def scoped(grads, state, params):
+        with jax.named_scope(OPTIM):
+            return update(grads, state, params)
+    return scoped
 
 
 # -------------------------------------------------------------- schedules --
@@ -92,7 +105,7 @@ def sgd(lr=1e-2, momentum: float = 0.9, nesterov: bool = False,
         new_p = _tmap(lambda p, g: (p - lr_t * g).astype(p.dtype), params, eff)
         return new_p, OptState(step=state.step + 1, inner=inner)
 
-    return Optimizer(init=init, update=update)
+    return Optimizer(init=init, update=_scoped(update))
 
 
 # ------------------------------------------------------------------ AdamW --
@@ -124,7 +137,7 @@ def adamw(lr=3e-4, b1: float = 0.9, b2: float = 0.95, eps: float = 1e-8,
         new_p = _tmap(step_fn, params, m, v)
         return new_p, OptState(step=t, inner={"m": m, "v": v})
 
-    return Optimizer(init=init, update=update)
+    return Optimizer(init=init, update=_scoped(update))
 
 
 # -------------------------------------------------------------- Adafactor --
@@ -176,4 +189,4 @@ def adafactor(lr=1e-3, decay: float = 0.8, eps: float = 1e-30,
         new_f = tdef.unflatten([o[1] for o in outs])
         return new_p, OptState(step=t, inner=new_f)
 
-    return Optimizer(init=init, update=update)
+    return Optimizer(init=init, update=_scoped(update))

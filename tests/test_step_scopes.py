@@ -106,6 +106,14 @@ def test_stages_edges_and_optimizer_all_appear(adatopk):
     assert S.Scope(S.OPTIM, "", "") in found
 
 
+def test_rad_step_opens_no_pipe_scope(adatopk):
+    """The GPipe step's scopes never appear in the RAD step's program."""
+    _, hlo = adatopk
+    assert not re.search(r'op_name="[^"]*\bpipe/', hlo)
+    assert {s.step for s in _scopes(hlo).values() if s is not None} == {
+        S.RAD}
+
+
 def test_no_edge_scope_without_compression():
     job = _job("none")
     assert not _planned_producers(job)

@@ -180,3 +180,65 @@ def test_rad_step_matmuls_stay_in_their_stage_scopes(v5e_step):
         if matmuls(text) and f"/{OPTIM}/" in body:
             fused_with_optim += 1
     assert fused_with_optim      # what makes the rule matter
+
+
+def test_pipeline_step_scopes_on_v5e_2x2(topo, monkeypatch):
+    """The GPipe step (smoke widths, 4 layers over a described 2 x 2 mesh,
+    Adam, the pod edge compressed by the codec's kernels) compiled for the
+    chip: as the benchmark reads the chip's fusions, every matmul falls in
+    `pipe/blocks` or `pipe/head` and every kernel call in the edge's
+    scope, in both directions."""
+    import re
+    from chipbench.scope_reduce import _CALLS, _computations, \
+        instruction_scopes
+    from repro.configs import resolve
+    from repro.distributed.pipeline import (make_pipeline_train_step,
+                                            stage_axes)
+    from repro.models import causal_lm
+    from repro.obs.scopes import classify
+    from repro.optim import adamw
+
+    monkeypatch.setattr(ops, "off_tpu", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("pod", "model"))
+    cfg = resolve("gpt2-xl").smoke.replace(n_layers=4, max_seq=64)
+    opt = adamw(1e-3)
+    shapes = jax.eval_shape(lambda: causal_lm.init(cfg,
+                                                   jax.random.PRNGKey(0)))
+    rep = NamedSharding(mesh, P())
+
+    def placed(tree, key):
+        sh = NamedSharding(mesh, P(stage_axes(mesh))) if key == "blocks" \
+            else rep
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            tree)
+
+    params = {k: placed(v, k) for k, v in shapes.items()}
+    state = jax.eval_shape(opt.init, shapes)
+    state = state._replace(
+        step=jax.ShapeDtypeStruct((), state.step.dtype, sharding=rep),
+        inner={"m": params, "v": params})
+    batch = {k: jax.ShapeDtypeStruct((4, 1, 64), jnp.int32, sharding=rep)
+             for k in ("tokens", "labels")}
+    with jax.set_mesh(mesh):
+        step = make_pipeline_train_step(cfg, mesh, opt, 4, 100.0,
+                                        use_kernel="auto")
+        hlo = jax.jit(step).lower(params, state, batch).compile().as_text()
+    table = instruction_scopes(hlo, classify)
+    kernels = {n: s for n, s in table.items()
+               if n.startswith(("_encode_pallas", "_decode_pallas"))}
+    assert kernels and {str(s) for s in kernels.values()} == {
+        "pipe/edge/block_1/fwd", "pipe/edge/block_1/bwd"}
+    comps = _computations(hlo)
+
+    def holds_matmul(text):
+        return bool(re.search(r"\s(?:dot|convolution)\(", text)) or any(
+            holds_matmul(t) for c in _CALLS.findall(text)
+            for _, t in comps.get(c, ()))
+
+    fused = {c for body in comps.values() for _, t in body
+             if re.search(r"\bfusion\(", t) for c in _CALLS.findall(t)}
+    matmuls = {str(table[n]) for comp, body in comps.items()
+               if comp not in fused for n, t in body if holds_matmul(t)}
+    assert matmuls == {"pipe/blocks/fwd", "pipe/blocks/bwd",
+                       "pipe/head/fwd", "pipe/head/bwd"}
