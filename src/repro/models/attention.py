@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
+from repro.kernels import flash_attention, ops as kernel_ops
 from .layers import apply_rope, dense, dense_init
 
 NEG_INF = -1e30
@@ -106,7 +107,11 @@ def attn_train(p, x: jax.Array, *, n_heads: int, n_kv: int, head_dim: int,
                x_kv: Optional[jax.Array] = None) -> jax.Array:
     """Full-sequence attention (training / encoder).  ``x_kv`` switches to
     cross-attention (no RoPE on keys of encoder memory by convention here —
-    both sides get positions of their own sequence)."""
+    both sides get positions of their own sequence).
+
+    On a TPU, self-attention without a window whose length the kernel can
+    block runs the blocked kernel (``kernels/flash_attention``); everything
+    else, and every path off a TPU, runs ``sdpa``."""
     src = x if x_kv is None else x_kv
     q, k, v = _project_qkv(p, x, src, n_heads, n_kv, head_dim)
     if rope_fraction > 0:
@@ -114,10 +119,13 @@ def attn_train(p, x: jax.Array, *, n_heads: int, n_kv: int, head_dim: int,
         kpos = jnp.arange(src.shape[1])[None]
         q = apply_rope(q, qpos, rope_fraction, rope_theta)
         k = apply_rope(k, kpos, rope_fraction, rope_theta)
-    mask = make_mask(x.shape[1], src.shape[1],
-                     causal and x_kv is None, window)
-    out = sdpa(q, k, v, mask)
     B, S = x.shape[:2]
+    if (x_kv is None and window is None and not kernel_ops.off_tpu()
+            and flash_attention.block_size(S, head_dim)):
+        out = flash_attention.attention(q, k, v, causal)
+    else:
+        out = sdpa(q, k, v, make_mask(S, src.shape[1],
+                                      causal and x_kv is None, window))
     return dense({"w": p["wo"]["w"]}, out.reshape(B, S, n_heads * head_dim))
 
 

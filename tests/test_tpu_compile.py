@@ -4,12 +4,14 @@ in f32 and bf16, the wire kernels also at the logits edge's own size
 (4 x 1024 x 50432 padded vocabulary, f32, 484 of every 4096 kept), and
 the codec as the four-chip GPipe step runs it (inside the ``check_vma``
 ``shard_map`` over a described 2 x 2 mesh, at the pod edge's micro-batch
-boundary).  Nothing runs: the chip's compiler must accept each program,
+boundary), and the blocked attention kernel inside the RAD and GPipe
+steps.  Nothing runs: the chip's compiler must accept each program,
 and the HLO must carry the kernel as a ``tpu_custom_call``.
 
 The topology is described inside a module fixture (one process may hold
 the TPU library at a time, so nothing here touches it at import)."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,7 +155,6 @@ def test_rad_step_matmuls_stay_in_their_stage_scopes(v5e_step):
     the matmul that computes the weight's gradient.  Every matmul still
     counts under the stage scope its own op_name names, so that no stage
     can read less time than its FLOPs need at the chip's peak."""
-    import re
     from chipbench.scope_reduce import _CALLS, _computations
     from repro.obs.scopes import OPTIM, classify
     _, hlo, table = v5e_step
@@ -182,25 +183,86 @@ def test_rad_step_matmuls_stay_in_their_stage_scopes(v5e_step):
     assert fused_with_optim      # what makes the rule matter
 
 
-def test_pipeline_step_scopes_on_v5e_2x2(topo, monkeypatch):
-    """The GPipe step (smoke widths, 4 layers over a described 2 x 2 mesh,
-    Adam, the pod edge compressed by the codec's kernels) compiled for the
-    chip: as the benchmark reads the chip's fusions, every matmul falls in
-    `pipe/blocks` or `pipe/head` and every kernel call in the edge's
-    scope, in both directions."""
-    import re
-    from chipbench.scope_reduce import _CALLS, _computations, \
-        instruction_scopes
+#: smoke widths with gpt2-xl's head size and a sequence of three 128
+#: blocks, so that every block takes the attention kernel
+ATTN_WIDTHS = dict(n_heads=2, n_kv_heads=2, head_dim=64)
+ATTN_SEQ = 384
+ATTN_CALLS = re.compile(r"%(_attn_(?:fwd|bwd)_pallas)[.\d]* = .*tpu_custom_call")
+
+
+def _lowered_attention_calls(text):
+    """``tpu_custom_call``s inside the attention kernel's functions of a
+    lowered (StableHLO) module."""
+    return sum(f.count("tpu_custom_call")
+               for f in text.split("func.func")[1:]
+               if f.split("(", 1)[0].strip().split("@")[-1].startswith(
+                   "_attn_"))
+
+
+def _rad_dense_step(one_chip, layers):
+    """The RAD step without compression at ``layers`` blocks, traced for the
+    chip: (lowered module text, compiled HLO text)."""
+    import unittest.mock
+    from repro.configs import resolve
+    from repro.launch.train import fusion_job
+    from repro.optim import adamw
+
+    cfg = resolve("gpt2-xl").smoke.replace(n_layers=layers, **ATTN_WIDTHS)
+    with unittest.mock.patch.object(ops, "off_tpu", lambda: False):
+        job = fusion_job(cfg, adamw(1e-3), batch=2, seq=ATTN_SEQ,
+                         compress="none")
+
+        def sds(tree):
+            return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=one_chip), tree)
+
+        batch = {k: jax.ShapeDtypeStruct((2, ATTN_SEQ), jnp.int32,
+                                         sharding=one_chip)
+                 for k in ("tokens", "labels")}
+        lowered = job.step.lower(sds(job.params), sds(job.opt_state), batch)
+        return lowered.as_text(), lowered.compile().as_text()
+
+
+def test_rad_step_lowers_the_attention_kernel_once_at_any_depth(one_chip):
+    """Every block of the RAD step runs the attention kernel, and the
+    program lowers it once per direction whatever its depth: the module
+    holds the same two kernel calls at 2 and at 4 layers (a kernel traced
+    per layer adds a call per layer, and set-up time with it).  The chip's
+    compiler inlines them into one forward and one backward call a layer,
+    each inside its stage's scope."""
+    from chipbench.scope_reduce import instruction_scopes
+    from repro.obs import scopes as S
+
+    lowered = {}
+    for layers in (2, 4):
+        text, hlo = _rad_dense_step(one_chip, layers)
+        lowered[layers] = _lowered_attention_calls(text)
+        calls = ATTN_CALLS.findall(hlo)
+        assert sorted(set(calls)) == ["_attn_bwd_pallas", "_attn_fwd_pallas"]
+        assert len(calls) == 2 * layers
+        table = instruction_scopes(hlo, S.classify)
+        kernels = {n: s for n, s in table.items() if n.startswith("_attn_")}
+        assert {(s.kind, s.direction) for s in kernels.values()} == {
+            (S.STAGE, "fwd"), (S.STAGE, "bwd")}
+    assert lowered[2] == lowered[4] == 2
+
+
+@pytest.fixture(scope="module")
+def v5e_pipeline_step(topo):
+    """The GPipe step (smoke widths with gpt2-xl's head size, 4 layers over
+    a described 2 x 2 mesh, Adam, the pod edge compressed by the codec's
+    kernels, every block on the attention kernel) compiled for the chip:
+    its optimized HLO text."""
+    import unittest.mock
     from repro.configs import resolve
     from repro.distributed.pipeline import (make_pipeline_train_step,
                                             stage_axes)
     from repro.models import causal_lm
-    from repro.obs.scopes import classify
     from repro.optim import adamw
 
-    monkeypatch.setattr(ops, "off_tpu", lambda: False)
     mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("pod", "model"))
-    cfg = resolve("gpt2-xl").smoke.replace(n_layers=4, max_seq=64)
+    cfg = resolve("gpt2-xl").smoke.replace(n_layers=4, max_seq=ATTN_SEQ,
+                                           **ATTN_WIDTHS)
     opt = adamw(1e-3)
     shapes = jax.eval_shape(lambda: causal_lm.init(cfg,
                                                    jax.random.PRNGKey(0)))
@@ -218,12 +280,25 @@ def test_pipeline_step_scopes_on_v5e_2x2(topo, monkeypatch):
     state = state._replace(
         step=jax.ShapeDtypeStruct((), state.step.dtype, sharding=rep),
         inner={"m": params, "v": params})
-    batch = {k: jax.ShapeDtypeStruct((4, 1, 64), jnp.int32, sharding=rep)
+    batch = {k: jax.ShapeDtypeStruct((4, 1, ATTN_SEQ), jnp.int32,
+                                     sharding=rep)
              for k in ("tokens", "labels")}
-    with jax.set_mesh(mesh):
+    with unittest.mock.patch.object(ops, "off_tpu", lambda: False), \
+            jax.set_mesh(mesh):
         step = make_pipeline_train_step(cfg, mesh, opt, 4, 100.0,
                                         use_kernel="auto")
-        hlo = jax.jit(step).lower(params, state, batch).compile().as_text()
+        return jax.jit(step).lower(params, state, batch).compile().as_text()
+
+
+def test_pipeline_step_scopes_on_v5e_2x2(v5e_pipeline_step):
+    """The GPipe step compiled for the chip: as the benchmark reads the
+    chip's fusions, every matmul falls in `pipe/blocks` or `pipe/head` and
+    every codec kernel call in the edge's scope, in both directions."""
+    from chipbench.scope_reduce import _CALLS, _computations, \
+        instruction_scopes
+    from repro.obs.scopes import classify
+
+    hlo = v5e_pipeline_step
     table = instruction_scopes(hlo, classify)
     kernels = {n: s for n, s in table.items()
                if n.startswith(("_encode_pallas", "_decode_pallas"))}
@@ -242,3 +317,20 @@ def test_pipeline_step_scopes_on_v5e_2x2(topo, monkeypatch):
                if comp not in fused for n, t in body if holds_matmul(t)}
     assert matmuls == {"pipe/blocks/fwd", "pipe/blocks/bwd",
                        "pipe/head/fwd", "pipe/head/bwd"}
+
+
+def test_pipeline_blocks_take_the_attention_kernel_on_v5e_2x2(
+        v5e_pipeline_step):
+    """Inside the ``check_vma`` ``shard_map``, in the rematerialized scan
+    over a stage's blocks, the attention kernel compiles: its forward (run
+    twice, once recomputed under ``jax.checkpoint``) and its backward, each
+    in `pipe/blocks`."""
+    from chipbench.scope_reduce import instruction_scopes
+    from repro.obs.scopes import classify
+
+    hlo = v5e_pipeline_step
+    assert set(ATTN_CALLS.findall(hlo)) == {"_attn_fwd_pallas",
+                                            "_attn_bwd_pallas"}
+    table = instruction_scopes(hlo, classify)
+    assert {str(s) for n, s in table.items() if n.startswith("_attn_")} == {
+        "pipe/blocks/fwd", "pipe/blocks/bwd"}
